@@ -28,6 +28,8 @@ import json
 import os
 
 if __name__ == "__main__" and "XLA_FLAGS" not in os.environ:
+    # eight virtual host devices stand in for the pools: pin the CPU backend
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 from typing import Dict, List

@@ -27,6 +27,8 @@ Run:  PYTHONPATH=src python examples/serve_disaggregated.py
 
 import os
 
+# eight virtual host devices stand in for the pools: pin the CPU backend
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import time
@@ -37,7 +39,6 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.core.aebs import ReplicaLayout, aebs_assign
 from repro.models import model as model_mod
-from repro.launch.mesh import use_mesh
 from repro.serving.engine import ServingEngine
 from repro.serving.request import WorkloadSpec, sample_requests
 from repro.serving.trace import poisson_arrivals
@@ -88,7 +89,11 @@ def pool_mode_demo():
 def spmd_mode_demo():
     print("=== B. SPMD deployment (full reduced model, 2×4 mesh) ===")
     cfg = get_config("qwen2-moe-a2.7b-reduced")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    # Auto axes: with Explicit ones (make_mesh's default) the EP layer's
+    # data-sharded output no longer matches the decode scan's carry type
+    mesh = jax.make_mesh(
+        (2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+    )
     params = model_mod.init_params(cfg, 0)
     layout = ReplicaLayout.round_robin(cfg.num_experts, 4, 2)
     moe_ctx = dict(
@@ -101,7 +106,7 @@ def spmd_mode_demo():
     )
     B, S = 4, 32
     tokens = jax.random.randint(jax.random.PRNGKey(3), (B, S), 0, cfg.vocab_size)
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         _, caches = model_mod.prefill(params, tokens, cfg, cache_len=S + 16)
         step = jax.jit(
             lambda p, t, c, i: model_mod.decode_step(p, t, c, i, cfg, extra={"moe_ctx": moe_ctx})

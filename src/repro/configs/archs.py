@@ -20,6 +20,7 @@ from repro.configs.zamba2_2_7b import CONFIG as ZAMBA2_27B
 from repro.configs.whisper_tiny import CONFIG as WHISPER_TINY
 from repro.configs.phi3_5_moe_42b import CONFIG as PHI35_MOE_42B
 from repro.configs.dsv2_lite import CONFIG as DSV2_LITE
+from repro.configs.dsv2_lite_4l import CONFIG as DSV2_LITE_4L
 from repro.configs.dsv2 import CONFIG as DSV2
 from repro.configs.scaled_ds_1 import CONFIG as SCALED_DS_1
 from repro.configs.scaled_ds_2 import CONFIG as SCALED_DS_2
@@ -41,7 +42,7 @@ ASSIGNED: Dict[str, ModelConfig] = {
 }
 
 PAPER_MODELS: Dict[str, ModelConfig] = {
-    c.name: c for c in (DSV2_LITE, DSV2, SCALED_DS_1, SCALED_DS_2)
+    c.name: c for c in (DSV2_LITE, DSV2_LITE_4L, DSV2, SCALED_DS_1, SCALED_DS_2)
 }
 
 REGISTRY: Dict[str, ModelConfig] = {**ASSIGNED, **PAPER_MODELS}

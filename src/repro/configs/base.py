@@ -88,6 +88,8 @@ class ModelConfig:
 
     # --- provenance ----------------------------------------------------------------
     source: str = ""  # citation
+    # every size cut from the published config: (key, published, here)
+    cuts: Tuple[Tuple[str, Any, Any], ...] = ()
 
     # -------------------------------------------------------------------------
     @property

@@ -39,7 +39,9 @@ class HardwareSpec:
     kernel_launch: float = 5e-6  # dispatch constant (c_a / c_e floor)
 
 
-# TPU v5e (target hardware of this repro; ICI ~50 GB/s/link, ~3 links usable)
+# TPU v5e (target hardware of this repro).  Peaks from Google Cloud's "TPU
+# v5e" documentation: 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+# of chip-to-chip interconnect (modeled as ~3 usable ICI links of 50 GB/s).
 TPU_V5E = HardwareSpec(
     name="tpu_v5e",
     peak_flops=197e12,
@@ -64,6 +66,28 @@ H100 = HardwareSpec(
     mem_bytes=80e9,
     devices_per_node=8,
 )
+
+# Accelerators this system has peaks for, keyed by ``jax.Device.device_kind``
+# (the kind JAX reports for a v5e chip is "TPU v5 lite").
+HARDWARE_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device) -> HardwareSpec:
+    """The :class:`HardwareSpec` of ``device``'s accelerator.
+
+    A TPU whose kind is not in :data:`HARDWARE_BY_DEVICE_KIND` is an error,
+    never a default: its exchange would be priced with another chip's rates.
+    Other backends (the CPU test path) keep the modeled v5e spec — the
+    hardware this system targets."""
+    if device.platform != "tpu":
+        return TPU_V5E
+    try:
+        return HARDWARE_BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware spec for TPU kind {device.device_kind!r}; add its "
+            "published peaks to HARDWARE_BY_DEVICE_KIND"
+        ) from None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,101 +1,120 @@
 """AEBS as a Pallas TPU kernel — the paper's §3.4 GPU kernel, TPU-native.
 
-Design (DESIGN.md §6): the scheduling workflow is two ``pallas_call``s.
+The scheduling workflow is three ``pallas_call``s:
 
-Kernel 1 (``_collect_and_greedy``) — grid over token blocks:
-  * stage 1 (token-parallel, VPU): each block folds its activated-expert
-    bitmap into a VMEM scratch accumulator via max (grid iterations on TPU
-    run sequentially per core, so scratch accumulation is well-defined);
-  * stage 2 (sequential, final grid step only): the greedy two-pass replica
-    selection of Algorithm 1 over ≤E experts (E ≤ 512 — a scalar-ish loop,
-    negligible next to the MoE GEMMs), producing ``act_rep`` and ``load``.
+Kernel 1 (``_collect``) — grid over token blocks, vector units: each block
+  folds its activated-expert bitmap into a lane-major ``[1, E]`` output
+  accumulator via max (grid iterations on TPU run sequentially per core, so
+  the accumulation is well-defined).
 
-Kernel 2 (``_rewrite``) — grid over token blocks: rewrite per-token logical
-EIDs to physical replica slots.  The gather is expressed as a one-hot matmul
-(MXU-friendly, avoids relying on dynamic-gather lowering support).
+Kernel 2 (``_greedy``) — no grid, scalar core: the greedy two-pass replica
+  selection of Algorithm 1 over ≤E experts.  Every table lives in SMEM and is
+  read one scalar at a time at a data-dependent index, which the vector units
+  cannot do (a dynamic vector slice does not lower on the TPU).  Produces
+  ``act_rep`` and ``load``.
 
-Both run identically on every MoE shard — Janus's synchronisation-free
+Kernel 3 (``_rewrite``) — grid over token blocks: rewrite per-token logical
+  EIDs to physical replica slots as a masked select-and-sum against the
+  ``[1, E]`` replica row (exact in int32, no gather).
+
+All run identically on every MoE shard — Janus's synchronisation-free
 redundant-compute trick carries over unchanged.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-def _collect_and_greedy_kernel(
-    eids_ref,  # [TB, k] int32 block (padded items = -1)
-    hosts_ref,  # [E, R] int32
-    counts_ref,  # [E, 1] int32
-    slot_of_ref,  # [E, n_e] int32
-    actrep_ref,  # out [E, 1] int32
-    load_ref,  # out [n_e, 1] int32
-    act_scratch,  # VMEM scratch [E, 1] int32
-    *,
-    num_blocks: int,
-):
-    i = pl.program_id(0)
-    E = hosts_ref.shape[0]
-    n_e = slot_of_ref.shape[1]
+from repro.kernels import resolve_interpret
 
-    @pl.when(i == 0)
+_BIG = 2**30  # load of a missing replica: never the argmin
+
+
+def _collect_kernel(eids_ref, act_ref):
+    """eids_ref [TB, k] (padded items = -1) → act_ref [1, E] bitmap (max-fold)."""
+
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        act_scratch[...] = jnp.zeros_like(act_scratch)
+        act_ref[...] = jnp.zeros_like(act_ref)
 
-    # ---- stage 1: token-parallel activation bitmap ----
+    blk = eids_ref[...]
+    E = act_ref.shape[1]
+    eye = jax.lax.broadcasted_iota(jnp.int32, (1, E), 1)
+    hits = act_ref[...]
+    for j in range(blk.shape[1]):
+        col = blk[:, j : j + 1]  # [TB, 1]
+        hits = jnp.maximum(
+            hits, (col == eye).astype(jnp.int32).max(axis=0, keepdims=True)
+        )
+    act_ref[...] = hits
+
+
+def _greedy_kernel(
+    act_ref,  # SMEM [E] int32 activation bitmap
+    hosts_ref,  # SMEM [E * R] int32, -1 = no replica
+    counts_ref,  # SMEM [E] int32 replica counts
+    slot_of_ref,  # SMEM [E * n_e] int32 global slot of expert e on instance g
+    rep_ref,  # SMEM out [E] int32 chosen slot per expert (-1 = inactive)
+    load_ref,  # SMEM out [n_e] int32 activated experts per instance
+    *,
+    E: int,
+    R: int,
+    n_e: int,
+):
+    for g in range(n_e):
+        load_ref[g] = jnp.int32(0)
+
+    def _clear(e, c):
+        rep_ref[e] = jnp.int32(-1)
+        return c
+
+    jax.lax.fori_loop(0, E, _clear, 0)
+
+    def assign_pass(want_multi: bool):
+        def body(e, c):
+            cnt = counts_ref[e]
+            eligible = (act_ref[e] > 0) & ((cnt > 1) == want_multi) & (cnt >= 1)
+
+            @pl.when(eligible)
+            def _assign():
+                # argmin over the expert's hosts of their current load; the
+                # first minimum wins, as jnp.argmin's does in the reference
+                best_load = jnp.int32(_BIG)
+                best_g = jnp.int32(0)
+                for r in range(R):
+                    h = hosts_ref[e * R + r]
+                    hs = jnp.maximum(h, 0)
+                    ld = jnp.where(h >= 0, load_ref[hs], jnp.int32(_BIG))
+                    better = ld < best_load
+                    best_load = jnp.where(better, ld, best_load)
+                    best_g = jnp.where(better, hs, best_g)
+                load_ref[best_g] = load_ref[best_g] + 1
+                rep_ref[e] = slot_of_ref[e * n_e + best_g]
+
+            return c
+
+        jax.lax.fori_loop(0, E, body, 0)
+
+    assign_pass(False)
+    assign_pass(True)
+
+
+def _rewrite_kernel(eids_ref, rep_ref, out_ref):
+    """slot_ids = act_rep[eids]: one replica per expert, so a masked sum over
+    the expert lanes selects it exactly; padded items stay -1."""
     blk = eids_ref[...]  # [TB, k]
-    eye = jax.lax.broadcasted_iota(jnp.int32, (1, 1, E), 2)
-    hits = (blk[:, :, None] == eye).any(axis=(0, 1))  # [E] bool
-    act_scratch[...] = jnp.maximum(act_scratch[...], hits.astype(jnp.int32)[:, None])
-
-    # ---- stage 2: greedy two-pass assignment (Algorithm 1), last block ----
-    @pl.when(i == num_blocks - 1)
-    def _greedy():
-        act = act_scratch[...][:, 0]  # [E]
-        hosts = hosts_ref[...]  # [E, R]
-        counts = counts_ref[...][:, 0]  # [E]
-        slot_of = slot_of_ref[...]  # [E, n_e]
-
-        def assign_pass(carry, want_multi):
-            def body(e, c):
-                load, rep = c
-                is_multi = counts[e] > 1
-                eligible = (act[e] > 0) & (is_multi == want_multi) & (counts[e] >= 1)
-                row = hosts[e]  # [R]
-                row_load = jnp.where(row >= 0, load[jnp.maximum(row, 0)], jnp.int32(2**30))
-                sel = jnp.argmin(row_load)
-                g = jnp.maximum(row[sel], 0)
-                slot = slot_of[e, g]
-                load = jnp.where(eligible, load.at[g].add(1), load)
-                rep = rep.at[e].set(jnp.where(eligible, slot, rep[e]))
-                return (load, rep)
-
-            return jax.lax.fori_loop(0, E, body, carry)
-
-        load0 = jnp.zeros((n_e,), jnp.int32)
-        rep0 = jnp.full((E,), -1, jnp.int32)
-        load1, rep1 = assign_pass((load0, rep0), False)
-        load2, rep2 = assign_pass((load1, rep1), True)
-        actrep_ref[...] = rep2[:, None]
-        load_ref[...] = load2[:, None]
-
-
-def _rewrite_kernel(eids_ref, actrep_ref, out_ref):
-    """slot_ids = act_rep[eids] via one-hot matmul (exact for values < 2^24)."""
-    blk = eids_ref[...]  # [TB, k]
-    E = actrep_ref.shape[0]
-    tb, k = blk.shape
-    eye = jax.lax.broadcasted_iota(jnp.int32, (tb * k, E), 1)
-    oh = (blk.reshape(tb * k, 1) == eye).astype(jnp.float32)
-    rep = actrep_ref[...][:, 0].astype(jnp.float32)  # [E]
-    vals = jnp.dot(oh, rep[:, None], preferred_element_type=jnp.float32)  # [tb*k, 1]
-    invalid = blk.reshape(tb * k, 1) < 0
-    out = jnp.where(invalid, -1.0, vals).astype(jnp.int32)
-    out_ref[...] = out.reshape(tb, k)
+    rep = rep_ref[...]  # [1, E]
+    eye = jax.lax.broadcasted_iota(jnp.int32, (1, rep.shape[1]), 1)
+    for j in range(blk.shape[1]):
+        col = blk[:, j : j + 1]  # [TB, 1]
+        val = jnp.where(col == eye, rep, 0).sum(axis=1, keepdims=True)
+        out_ref[:, j : j + 1] = jnp.where(col < 0, -1, val)
 
 
 def aebs_pallas(
@@ -105,11 +124,13 @@ def aebs_pallas(
     slot_of: jax.Array,  # [E, n_e]
     *,
     block_tokens: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns (slot_ids [T, k], load [n_e], act_rep [E])."""
+    interpret = resolve_interpret(interpret)
     T, k = eids.shape
     E, n_e = slot_of.shape
+    R = hosts.shape[1]
     TB = min(block_tokens, T)
     pad = (-T) % TB
     if pad:
@@ -117,37 +138,42 @@ def aebs_pallas(
     Tp = eids.shape[0]
     num_blocks = Tp // TB
 
-    actrep, load = pl.pallas_call(
-        functools.partial(_collect_and_greedy_kernel, num_blocks=num_blocks),
+    act = pl.pallas_call(
+        _collect_kernel,
         grid=(num_blocks,),
-        in_specs=[
-            pl.BlockSpec((TB, k), lambda i: (i, 0)),
-            pl.BlockSpec((E, hosts.shape[1]), lambda i: (0, 0)),
-            pl.BlockSpec((E, 1), lambda i: (0, 0)),
-            pl.BlockSpec((E, n_e), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((E, 1), lambda i: (0, 0)),
-            pl.BlockSpec((n_e, 1), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((E, 1), jnp.int32),
-            jax.ShapeDtypeStruct((n_e, 1), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((E, 1), jnp.int32)],
+        in_specs=[pl.BlockSpec((TB, k), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((1, E), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, E), jnp.int32),
         interpret=interpret,
-    )(eids, hosts, counts[:, None], slot_of)
+    )(eids)
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    actrep, load = pl.pallas_call(
+        functools.partial(_greedy_kernel, E=E, R=R, n_e=n_e),
+        in_specs=[smem] * 4,
+        out_specs=[smem, smem],
+        out_shape=[
+            jax.ShapeDtypeStruct((E,), jnp.int32),
+            jax.ShapeDtypeStruct((n_e,), jnp.int32),
+        ],
+        interpret=interpret,
+    )(
+        act.reshape(E),
+        hosts.astype(jnp.int32).reshape(E * R),
+        counts.astype(jnp.int32),
+        slot_of.astype(jnp.int32).reshape(E * n_e),
+    )
 
     slot_ids = pl.pallas_call(
         _rewrite_kernel,
         grid=(num_blocks,),
         in_specs=[
             pl.BlockSpec((TB, k), lambda i: (i, 0)),
-            pl.BlockSpec((E, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, E), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((TB, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Tp, k), jnp.int32),
         interpret=interpret,
-    )(eids, actrep)
+    )(eids, actrep.reshape(1, E))
 
-    return slot_ids[:T], load[:, 0], actrep[:, 0]
+    return slot_ids[:T], load, actrep

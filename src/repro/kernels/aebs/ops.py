@@ -1,7 +1,7 @@
 """Jit'd public wrapper for the AEBS kernel.
 
-On CPU (this container) the kernel body executes via ``interpret=True``;
-on TPU it compiles to Mosaic.  The wrapper handles padding and exposes the
+On a TPU the kernel compiles to Mosaic; on any other backend it runs in the
+Pallas interpreter (``repro.kernels.resolve_interpret``).  The wrapper handles padding and exposes the
 same (slot_ids, load, act_rep) contract as ``repro.core.aebs.aebs_assign``.
 """
 
@@ -14,10 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.aebs.kernel import aebs_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("num_instances", "block_tokens"))
@@ -33,7 +29,6 @@ def aebs_schedule(
         tables["replica_counts"],
         tables["slot_of"],
         block_tokens=block_tokens,
-        interpret=not _on_tpu(),
     )
 
 

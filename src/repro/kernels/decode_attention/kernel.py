@@ -16,11 +16,14 @@ term it addresses is c_kv·b·S_ctx of Eq. 1b.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 NEG_INF = -1.0e30
 
@@ -133,7 +136,7 @@ def decode_attention_int8_pallas(
     *,
     block_kv: int = 512,
     logit_cap: float = 0.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     B, nh, hd = q.shape
     _, S, nkv, _ = k_cache.shape
@@ -168,7 +171,7 @@ def decode_attention_int8_pallas(
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(valid, qg, k_cache, v_cache, k_scale, v_scale)
 
     return out.reshape(B, nkv, G, hd).reshape(B, nh, hd)
@@ -177,13 +180,13 @@ def decode_attention_int8_pallas(
 def _paged_decode_attn_kernel(
     bt_ref,  # scalar-prefetch [B, nblk] int32 — per-slot block table
     len_ref,  # scalar-prefetch [B] int32 — per-slot valid lengths
-    q_ref,  # [1, 1, 1, G, hd]
-    k_ref,  # [1, ps, 1, hd] — page bt[b, j] of the pool
-    v_ref,  # [1, ps, 1, hd]
-    o_ref,  # [1, 1, 1, G, hd]
-    m_scr,  # VMEM [G, 1] f32
-    l_scr,  # VMEM [G, 1] f32
-    acc_scr,  # VMEM [G, hd] f32
+    q_ref,  # [1, n_kv, G, hd]
+    k_ref,  # [1, ps, n_kv, hd] — page bt[b, j] of the pool, every KV head
+    v_ref,  # [1, ps, n_kv, hd]
+    o_ref,  # [1, n_kv, G, hd]
+    m_scr,  # VMEM [n_kv, G, 1] f32
+    l_scr,  # VMEM [n_kv, G, 1] f32
+    acc_scr,  # VMEM [n_kv, G, hd] f32
     *,
     num_kv_blocks: int,
     page_size: int,
@@ -193,9 +196,14 @@ def _paged_decode_attn_kernel(
     blocks in order, and the scalar-prefetched block table redirects the K/V
     BlockSpecs to the physical page (the slot-indirect `expert_ffn` idiom).
     Unbacked table entries point at the null page; per-slot ``len_ref``
-    masking zeroes whatever garbage lives there."""
+    masking zeroes whatever garbage lives there.
+
+    A block is a whole page — all KV heads — because the TPU tiles the last
+    two dims ``(n_kv, hd)`` of the pool: a one-head block ``(ps, 1, hd)``
+    breaks the 8×128 tiling rule.  Each head is a strided read of the page."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
+    n_kv = q_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
@@ -203,29 +211,32 @@ def _paged_decode_attn_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0, 0].astype(jnp.float32)  # [G, hd]
-    k = k_ref[0, :, 0].astype(jnp.float32)  # [ps, hd]
-    v = v_ref[0, :, 0].astype(jnp.float32)  # [ps, hd]
-    hd = q.shape[-1]
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * (hd**-0.5)  # [G, ps]
-    if logit_cap > 0.0:
-        s = logit_cap * jnp.tanh(s / logit_cap)
     pos = j * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-    s = jnp.where(pos < len_ref[b], s, NEG_INF)
+    valid = pos < len_ref[b]
+    for h in range(n_kv):
+        q = q_ref[0, h].astype(jnp.float32)  # [G, hd]
+        k = k_ref[0, :, h, :].astype(jnp.float32)  # [ps, hd]
+        v = v_ref[0, :, h, :].astype(jnp.float32)  # [ps, hd]
+        hd = q.shape[-1]
 
-    m_prev, l_prev = m_scr[...], l_scr[...]  # [G,1]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)  # [G, ps]
-    l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + jnp.dot(p, v, preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
-    l_scr[...] = l_new
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * (hd**-0.5)  # [G, ps]
+        if logit_cap > 0.0:
+            s = logit_cap * jnp.tanh(s / logit_cap)
+        s = jnp.where(valid, s, NEG_INF)
+
+        m_prev, l_prev = m_scr[h], l_scr[h]  # [G,1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)  # [G, ps]
+        l_scr[h] = l_prev * corr + p.sum(axis=-1, keepdims=True)
+        acc_scr[h] = acc_scr[h] * corr + jnp.dot(p, v, preferred_element_type=jnp.float32)
+        m_scr[h] = m_new
 
     @pl.when(j == num_kv_blocks - 1)
     def _finish():
-        o_ref[0, 0, 0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention_pallas(
@@ -236,7 +247,7 @@ def paged_decode_attention_pallas(
     lengths: jax.Array,  # [B] int32 — per-slot valid lengths
     *,
     logit_cap: float = 0.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Paged flash decode.  Returns attention output [B, n_heads, hd].
 
@@ -247,21 +258,21 @@ def paged_decode_attention_pallas(
     P, ps, nkv, _ = k_pages.shape
     G = nh // nkv
     nblk = block_tables.shape[1]
-    qg = q.reshape(B, nkv, G, hd)[:, :, None, :, :]  # [B, nkv, 1, G, hd]
+    qg = q.reshape(B, nkv, G, hd)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, nkv, nblk),
+        grid=(B, nblk),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, G, hd), lambda b, h, j, bt, ln: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd), lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, hd), lambda b, h, j, bt, ln: (bt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, nkv, G, hd), lambda b, j, bt, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((1, ps, nkv, hd), lambda b, j, bt, ln: (bt[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, ps, nkv, hd), lambda b, j, bt, ln: (bt[b, j], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, G, hd), lambda b, h, j, bt, ln: (b, h, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, nkv, G, hd), lambda b, j, bt, ln: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            pltpu.VMEM((nkv, G, 1), jnp.float32),
+            pltpu.VMEM((nkv, G, 1), jnp.float32),
+            pltpu.VMEM((nkv, G, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -272,11 +283,11 @@ def paged_decode_attention_pallas(
             logit_cap=logit_cap,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, nkv, 1, G, hd), q.dtype),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((B, nkv, G, hd), q.dtype),
+        interpret=resolve_interpret(interpret),
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), qg, k_pages, v_pages)
 
-    return out.reshape(B, nkv, G, hd).reshape(B, nh, hd)
+    return out.reshape(B, nh, hd)
 
 
 def decode_attention_pallas(
@@ -287,7 +298,7 @@ def decode_attention_pallas(
     *,
     block_kv: int = 512,
     logit_cap: float = 0.0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Returns attention output [B, n_heads, hd]."""
     B, nh, hd = q.shape
@@ -321,7 +332,7 @@ def decode_attention_pallas(
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(valid, qg, k_cache, v_cache)
 
     return out.reshape(B, nkv, G, hd).reshape(B, nh, hd)

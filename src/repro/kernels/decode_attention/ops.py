@@ -14,10 +14,6 @@ from repro.kernels.decode_attention.kernel import (
 from repro.kernels.decode_attention.ref import paged_decode_attention_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("block_kv", "logit_cap"))
 def decode_attention(q, k_cache, v_cache, valid_len, block_kv: int = 512, logit_cap: float = 0.0):
     S = k_cache.shape[1]
@@ -26,7 +22,7 @@ def decode_attention(q, k_cache, v_cache, valid_len, block_kv: int = 512, logit_
         bk //= 2
     return decode_attention_pallas(
         q, k_cache, v_cache, valid_len,
-        block_kv=bk, logit_cap=logit_cap, interpret=not _on_tpu(),
+        block_kv=bk, logit_cap=logit_cap,
     )
 
 
@@ -42,7 +38,7 @@ def decode_attention_int8(
         bk //= 2
     return decode_attention_int8_pallas(
         q, k_cache, v_cache, k_scale, v_scale, valid_len,
-        block_kv=bk, logit_cap=logit_cap, interpret=not _on_tpu(),
+        block_kv=bk, logit_cap=logit_cap,
     )
 
 
@@ -62,5 +58,5 @@ def paged_decode_attention(
         )
     return paged_decode_attention_pallas(
         q, k_pages, v_pages, block_tables, lengths,
-        logit_cap=logit_cap, interpret=not _on_tpu(),
+        logit_cap=logit_cap,
     )

@@ -31,10 +31,14 @@ pre-existing tests drive it.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _expert_ffn_kernel(
@@ -72,7 +76,7 @@ def expert_ffn_pallas(
     slot_to_expert: jax.Array | None = None,  # [S] int32, -1 → skip; None = identity
     *,
     ff_tile: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """SwiGLU expert FFN per slot with slot-indirect weight reads.
 
@@ -115,5 +119,5 @@ def expert_ffn_pallas(
         _expert_ffn_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, CAP, d), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(slot_to_expert, active, x, w_gate, w_up, w_down)

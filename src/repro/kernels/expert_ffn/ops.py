@@ -9,10 +9,6 @@ import jax
 from repro.kernels.expert_ffn.kernel import expert_ffn_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _ff_tile(f: int, ff_tile: int) -> int:
     ft = ff_tile
     while f % ft:
@@ -25,7 +21,7 @@ def expert_ffn(x, w_gate, w_up, w_down, active, ff_tile: int = 512):
     """Stacked-weights form: weights [S, d, f], one slab per slot."""
     return expert_ffn_pallas(
         x, w_gate, w_up, w_down, active,
-        ff_tile=_ff_tile(w_gate.shape[-1], ff_tile), interpret=not _on_tpu(),
+        ff_tile=_ff_tile(w_gate.shape[-1], ff_tile),
     )
 
 
@@ -39,5 +35,5 @@ def expert_ffn_grouped(x, w_gate, w_up, w_down, slot_to_expert, active, ff_tile:
     """
     return expert_ffn_pallas(
         x, w_gate, w_up, w_down, active, slot_to_expert,
-        ff_tile=_ff_tile(w_gate.shape[-1], ff_tile), interpret=not _on_tpu(),
+        ff_tile=_ff_tile(w_gate.shape[-1], ff_tile),
     )

@@ -1,5 +1,9 @@
 import os
 
+# 512 virtual host devices stand in for the production mesh, so the dry-run
+# must run on the CPU backend even on a host with a TPU (where the fan-out's
+# children would otherwise fight over the chip).
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_DRYRUN_EXTRA_FLAGS", "")
@@ -25,8 +29,8 @@ import time  # noqa: E402
 
 import jax  # noqa: E402
 
-from repro.configs import REGISTRY, SHAPES, get_config, shape_supported  # noqa: E402
-from repro.launch.mesh import make_production_mesh, use_mesh  # noqa: E402
+from repro.configs import PAPER_MODELS, REGISTRY, SHAPES, get_config, shape_supported  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.steps import BUILDERS  # noqa: E402
 from repro.roofline import analysis  # noqa: E402
 
@@ -47,7 +51,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str) -> dict:
     mesh_name = "x".join(str(s) for s in mesh.devices.shape)
     chips = mesh.devices.size
     t0 = time.perf_counter()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step, abs_args = BUILDERS[shape.kind](cfg, mesh, shape)
         lowered = step.lower(*abs_args)
         t_lower = time.perf_counter() - t0
@@ -95,7 +99,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str) -> dict:
     return rec
 
 
-PAPER_ARCHS = ("dsv2-lite", "dsv2", "scaled-ds-1", "scaled-ds-2")
+PAPER_ARCHS = tuple(PAPER_MODELS)
 
 
 def all_combos(include_paper: bool = False):
@@ -130,7 +134,7 @@ def fan_out(out_dir: str, multi_pod_also: bool, jobs: int, include_paper: bool =
     while tasks or running:
         while tasks and len(running) < jobs:
             cmd = tasks.pop(0)
-            running.append((cmd, subprocess.Popen(cmd)))
+            running.append((cmd, subprocess.Popen(cmd, env=dict(os.environ, JAX_PLATFORMS="cpu"))))
         time.sleep(2)
         still = []
         for cmd, p in running:

@@ -11,21 +11,6 @@ from __future__ import annotations
 import jax
 
 
-def use_mesh(mesh):
-    """Version-compat context manager for entering a mesh.
-
-    ``jax.set_mesh`` (newer releases) → ``jax.sharding.use_mesh`` (transition
-    releases) → the ``Mesh`` object itself (a context manager on every
-    version).  One shim shared by launch/dryrun, the serve examples, and the
-    EP subprocess tests so no caller hard-codes a jax API level.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)

@@ -1,8 +1,11 @@
-"""Serving launcher: run the continuous-batching engine (CPU-scale, reduced
-configs) with the Janus scheduled-MoE path and the autoscaling controller.
+"""Serving launcher: run the continuous-batching engine with the Janus
+scheduled-MoE path on any registered config.  ``--arch`` is taken literally:
+on the CPU serve a ``-reduced`` config, on a TPU one at published widths.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-moe-a2.7b \
-        --rate 20 --duration 2 --scheduler aebs
+    PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.serve \
+        --arch qwen2-moe-a2.7b-reduced --rate 20 --duration 2 --scheduler aebs
+    PYTHONPATH=src python -m repro.launch.serve --arch dsv2-lite-4l \
+        --kv-page-size 16 --cache-len 2048 --max-batch 16     # on a TPU v5e
 """
 
 from __future__ import annotations
@@ -12,9 +15,13 @@ import argparse
 import numpy as np
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen2-moe-a2.7b")
+    ap.add_argument(
+        "--arch", default="qwen2-moe-a2.7b-reduced",
+        help="registered config name, taken literally (append -reduced for "
+        "the tiny CPU variant)",
+    )
     ap.add_argument("--rate", type=float, default=20.0, help="requests/s")
     ap.add_argument("--duration", type=float, default=2.0, help="seconds of arrivals")
     ap.add_argument("--scheduler", default="aebs", choices=["aebs", "random", "token_hash", "none"])
@@ -107,8 +114,9 @@ def main():
     )
     ap.add_argument(
         "--draft", default=None, metavar="ARCH",
-        help="enable speculative decode with ARCH (reduced config) as the "
-        "draft model; pass the target --arch itself for self-drafting "
+        help="enable speculative decode with ARCH (a registered config, "
+        "taken literally) as the draft model; pass the target --arch itself "
+        "for self-drafting "
         "(acceptance 1.0 — useful for overhead measurement).  Output streams "
         "stay bit-identical to plain greedy regardless of the draft",
     )
@@ -117,54 +125,37 @@ def main():
         help="draft tokens proposed per verify step (default 2 when --draft "
         "is set); each step emits 1..K+1 tokens",
     )
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def build_engine(args: argparse.Namespace, params=None):
+    """The :class:`ServingEngine` that ``main`` serves with, built from
+    parsed arguments.  ``params`` passes in weights already made from
+    ``args.seed`` so that several engines can share them."""
     from repro.configs import get_config
     from repro.core.amax import make_routing_trace
     from repro.core.placement import build_layout
     from repro.models import model as model_mod
     from repro.serving.engine import ServingEngine
-    from repro.serving.request import WorkloadSpec, sample_requests, shared_prefix_spec
-    from repro.serving.trace import TraceSpec, poisson_arrivals
 
-    cfg = get_config(args.arch + "-reduced")
-    params = model_mod.init_params(cfg, args.seed)
+    cfg = get_config(args.arch)
+    if params is None:
+        params = model_mod.init_params(cfg, args.seed)
     draft_config = None
     if args.draft is not None:
-        draft_config = cfg if args.draft == args.arch else get_config(args.draft + "-reduced")
+        draft_config = cfg if args.draft == args.arch else get_config(args.draft)
     layout = None
     if cfg.has_moe and args.scheduler != "none":
         C = args.slots or (cfg.num_experts // args.n_instances + 1)
         trace = make_routing_trace(2048, cfg.num_experts, cfg.top_k, skew=0.8, seed=args.seed)
         layout = build_layout(trace, cfg.num_experts, args.n_instances, C)
-    if args.trace is not None:
-        with open(args.trace) as fh:
-            tspec = TraceSpec.from_json(fh.read())
-        reqs = tspec.build(vocab_size=cfg.vocab_size, with_prompts=True)
-    else:
-        if args.workload == "shared-prefix":
-            spec = shared_prefix_spec(vocab_size=cfg.vocab_size)
-        else:
-            spec = WorkloadSpec(
-                mean_input=8, mean_output=24, vocab_size=cfg.vocab_size, max_input=48, max_output=64
-            )
-        reqs = sample_requests(spec, poisson_arrivals(args.rate, args.duration, args.seed), with_prompts=True)
-    for r in reqs:
-        if args.slo_ttft is not None and r.ttft_slo is None:
-            r.ttft_slo = args.slo_ttft
-        if args.slo_tpot is not None and r.tpot_slo is None:
-            r.tpot_slo = args.slo_tpot
-    if args.request_deadline is not None:
-        for r in reqs:
-            if r.deadline is None:
-                r.deadline = r.arrival + args.request_deadline
     fault_plan = None
     if args.fault_plan is not None:
         from repro.serving.faults import FaultPlan
 
         with open(args.fault_plan) as fh:
             fault_plan = FaultPlan.from_json(fh.read())
-    eng = ServingEngine(
+    return ServingEngine(
         cfg,
         params,
         max_batch=args.max_batch,
@@ -187,19 +178,59 @@ def main():
         draft_config=draft_config,
         spec_k=args.spec_k,
     )
+
+
+def build_requests(args: argparse.Namespace, cfg):
+    """The request list ``main`` serves: a trace file or a sampled workload,
+    stamped with the SLO and deadline arguments."""
+    from repro.serving.request import WorkloadSpec, sample_requests, shared_prefix_spec
+    from repro.serving.trace import TraceSpec, poisson_arrivals
+
+    if args.trace is not None:
+        with open(args.trace) as fh:
+            tspec = TraceSpec.from_json(fh.read())
+        reqs = tspec.build(vocab_size=cfg.vocab_size, with_prompts=True)
+    else:
+        if args.workload == "shared-prefix":
+            spec = shared_prefix_spec(vocab_size=cfg.vocab_size)
+        else:
+            spec = WorkloadSpec(
+                mean_input=8, mean_output=24, vocab_size=cfg.vocab_size, max_input=48, max_output=64
+            )
+        reqs = sample_requests(spec, poisson_arrivals(args.rate, args.duration, args.seed), with_prompts=True)
+    for r in reqs:
+        if args.slo_ttft is not None and r.ttft_slo is None:
+            r.ttft_slo = args.slo_ttft
+        if args.slo_tpot is not None and r.tpot_slo is None:
+            r.tpot_slo = args.slo_tpot
+    if args.request_deadline is not None:
+        for r in reqs:
+            if r.deadline is None:
+                r.deadline = r.arrival + args.request_deadline
+    return reqs
+
+
+def main(argv=None):
+    from repro.launch.compile_cache import enable_compile_cache
+
+    args = parse_args(argv)
+    enable_compile_cache()
+    eng = build_engine(args)
+    cfg = eng.cfg
+    reqs = build_requests(args, cfg)
     print(
         f"serving {len(reqs)} requests on {cfg.name} "
         f"(scheduler={args.scheduler}, executor={args.executor}, "
         f"admission={eng.admission}, sched={args.sched}, "
         f"n_prefill={args.n_prefill}"
         + (f", trace={args.trace}" if args.trace else "")
-        + (f", fault_plan={args.fault_plan}" if fault_plan else "")
+        + (f", fault_plan={args.fault_plan}" if args.fault_plan else "")
         + ")"
     )
     m = eng.run(reqs)
     for k, v in m.items():
         print(f"  {k:20s} {v:.4f}" if isinstance(v, float) else f"  {k:20s} {v}")
-    if fault_plan is not None and eng.degraded_reason:
+    if eng.faults is not None and eng.degraded_reason:
         print(f"  degraded to mono executor: {eng.degraded_reason}")
 
 

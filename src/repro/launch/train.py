@@ -30,6 +30,7 @@ def main():
     if args.dry_run:
         import os
 
+        os.environ["JAX_PLATFORMS"] = "cpu"  # the virtual mesh is host devices
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
         from repro.launch.dryrun import run_one
 

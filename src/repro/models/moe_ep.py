@@ -49,7 +49,6 @@ from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models.ffn import ffn
@@ -231,12 +230,12 @@ def moe_layer_ep(
         if mode == "scheduled":
             aux_specs["load"] = P(None)
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(xspec, aux_specs),
-        check_rep=False,
+        check_vma=False,
     )(x, router_w, weights["w_gate"], weights["w_up"], weights["w_down"], *operands)
 
     if "shared" in params:

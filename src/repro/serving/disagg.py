@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.aebs import ReplicaLayout, aebs_assign
-from repro.core.comm import TPU_V5E, CommConfig, HardwareSpec, adaptive_two_phase
+from repro.core.comm import CommConfig, HardwareSpec, adaptive_two_phase, hardware_for
 from repro.core.disagg import DevicePools, DisaggConfig, plan_exchange
 from repro.core.disagg import reconfigure as disagg_reconfigure
 from repro.models import model as model_mod
@@ -107,7 +107,7 @@ class DisaggExecutor:
         scheduler: Callable = aebs_assign,
         capacity: Optional[int] = None,
         ping_pong: bool = False,
-        hw: HardwareSpec = TPU_V5E,
+        hw: Optional[HardwareSpec] = None,  # default: looked up by device kind
         devices: Optional[Sequence[jax.Device]] = None,
         kv_page_size: Optional[int] = None,
         kv_num_pages: Optional[int] = None,
@@ -137,7 +137,7 @@ class DisaggExecutor:
         self.scheduler = scheduler
         self.capacity = capacity
         self.ping_pong = ping_pong
-        self.hw = hw
+        self.hw = hw if hw is not None else hardware_for(pools.attn_devices[0])
         self.max_batch = max_batch
         self.cache_len = cache_len
         self.kv_page_size = kv_page_size
@@ -220,8 +220,13 @@ class DisaggExecutor:
         devices.  ``caches`` is the stacked engine-format cache dict to
         re-shard (zeros when None)."""
         cfg = self.cfg
-        if caches is None:
-            caches = model_mod.init_decode_caches(cfg, self.max_batch, self.cache_len)
+        fresh = caches is None
+        if fresh:
+            # shapes only: fresh shards are zeroed on their own device, so the
+            # whole batch's cache never stages on one device or on the host
+            caches = jax.eval_shape(
+                lambda: model_mod.init_decode_caches(cfg, self.max_batch, self.cache_len)
+            )
 
         pools = self.pools
         bounds = _shard_bounds(self.max_batch, n_attn)
@@ -272,7 +277,7 @@ class DisaggExecutor:
         if self.kv_page_size is not None:
             ps = self.kv_page_size
             np_caches = {
-                name: np.asarray(caches[name])
+                name: caches[name] if fresh else np.asarray(caches[name])
                 for name in _KV_KEYS.values()
                 if name in caches
             }
@@ -299,6 +304,13 @@ class DisaggExecutor:
                     for short, name in _KV_KEYS.items():
                         if name not in np_caches:
                             continue
+                        if fresh:
+                            spec = np_caches[name]  # [L, B, S, ...]
+                            layer[short] = jnp.zeros(
+                                (pager.num_pages, ps, *spec.shape[3:]), spec.dtype,
+                                device=dev,
+                            )
+                            continue
                         src = np_caches[name][l]  # [B, S, ...]
                         pool = np.zeros(
                             (pager.num_pages, ps, *src.shape[2:]), src.dtype
@@ -320,7 +332,14 @@ class DisaggExecutor:
                 for l in range(n_kv_layers):
                     per_layer.append(
                         {
-                            short: jax.device_put(caches[name][l, s.lo : s.hi], dev)
+                            short: (
+                                jnp.zeros(
+                                    (s.rows, *caches[name].shape[2:]),
+                                    caches[name].dtype, device=dev,
+                                )
+                                if fresh
+                                else jax.device_put(caches[name][l, s.lo : s.hi], dev)
+                            )
                             for short, name in _KV_KEYS.items()
                             if name in caches
                         }
@@ -375,31 +394,34 @@ class DisaggExecutor:
         stx = np.asarray(layout.slot_to_expert)
         self._moe_params = []
         for g, dev in enumerate(self.pools.moe_devices):
-            local = np.maximum(stx[g], 0)
+            local = jnp.asarray(np.maximum(stx[g], 0))
             layers = []
             for per, pos, kind, _ in self._layers:
                 if kind != "moe":
                     layers.append(None)
                     continue
                 mp = self._layer_param(per, pos)["moe"]
+                # each layer's slot copy moves to the instance's device as
+                # soon as it is gathered: only one layer's gather is ever
+                # transient on the device holding the logical weights
                 layers.append(
-                    {
-                        "router": mp["router"],
-                        "w": {
-                            k: jnp.take(mp[k], jnp.asarray(local), axis=0)
-                            for k in ("w_gate", "w_up", "w_down")
+                    jax.device_put(
+                        {
+                            "router": mp["router"],
+                            "w": {
+                                k: jnp.take(mp[k], local, axis=0)
+                                for k in ("w_gate", "w_up", "w_down")
+                            },
                         },
-                    }
+                        dev,
+                    )
                 )
             self._moe_params.append(
-                jax.device_put(
-                    {
-                        "layers": layers,
-                        "tables": tables,
-                        "lo": jnp.int32(g * self.C),
-                    },
-                    dev,
-                )
+                {
+                    "layers": layers,
+                    "tables": jax.device_put(tables, dev),
+                    "lo": jax.device_put(jnp.int32(g * self.C), dev),
+                }
             )
 
     def _build_attn_jits(self) -> None:
@@ -1140,7 +1162,7 @@ class DisaggExecutor:
             )
         logits = jnp.concatenate(
             [
-                jax.device_put(logit_shards[lo], jax.devices()[0])
+                jax.device_put(logit_shards[lo], pools.attn_devices[0])
                 for lo in sorted(logit_shards)
             ],
             axis=0,

@@ -10,6 +10,7 @@ from repro.core.comm import (
     CommConfig,
     adaptive_two_phase,
     agate_cost,
+    hardware_for,
     layer_comm_time,
     one_phase_cost,
     two_phase_case1,
@@ -111,3 +112,17 @@ def test_cost_monotone_in_batch_prop(c, factor):
     bigger = dataclasses.replace(c, batch=c.batch * factor)
     assert adaptive_two_phase(bigger)[0] >= adaptive_two_phase(c)[0]
     assert one_phase_cost(bigger) >= one_phase_cost(c)
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_hardware_spec_by_device_kind():
+    """A TPU is priced by its own kind; an unknown TPU kind is an error, not
+    a default; other backends keep the modeled v5e spec."""
+    assert hardware_for(_FakeDevice("tpu", "TPU v5 lite")) is TPU_V5E
+    assert hardware_for(_FakeDevice("cpu", "cpu")) is TPU_V5E
+    with pytest.raises(ValueError, match="TPU v9"):
+        hardware_for(_FakeDevice("tpu", "TPU v9"))

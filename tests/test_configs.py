@@ -38,6 +38,19 @@ def test_exact_dims(name):
     assert cfg.source  # every config cites its source
 
 
+def test_dsv2_lite_4l_cuts_depth_only():
+    """The chip configuration keeps every dsv2-lite width; only depth is cut,
+    and the cut is listed in the config itself."""
+    full, cut = REGISTRY["dsv2-lite"], get_config("dsv2-lite-4l")
+    assert cut.num_layers == 4
+    assert cut.cuts == (("num_layers", full.num_layers, 4),)
+    for key in ("d_model", "num_heads", "num_kv_heads", "head_dim", "vocab_size",
+                "num_experts", "num_shared_experts", "top_k", "d_ff_expert"):
+        assert getattr(cut, key) == getattr(full, key), key
+    assert cut.kv_bytes_per_token() == 32 * 1024  # 32 KiB per token at 4 layers
+    assert 2.5e9 < cut.total_params() < 2.6e9
+
+
 def test_expert_memory_dominates():
     """Table 1: expert params dominate MoE model memory (≥85% for big MoE)."""
     assert REGISTRY["phi3.5-moe-42b-a6.6b"].expert_param_fraction() > 0.9

@@ -209,3 +209,14 @@ def test_decode_attention_int8_sweep(B, nh, nkv, hd, S):
     full = decode_attention_ref(q, kc_f, vc_f, vl)
     err = np.abs(np.asarray(got) - np.asarray(full)).max()
     assert err < 0.05
+
+
+def test_interpret_mode_follows_backend():
+    """Off the TPU the kernels interpret by default; an explicit False still
+    compiles for the TPU (the described-chip compile path)."""
+    from repro.kernels import resolve_interpret
+
+    assert jax.default_backend() != "tpu"
+    assert resolve_interpret(None) is True
+    assert resolve_interpret(False) is False
+    assert resolve_interpret(True) is True

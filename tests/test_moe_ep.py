@@ -15,7 +15,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.core.aebs import ReplicaLayout, aebs_assign
-from repro.launch.mesh import use_mesh
 from repro.models import moe as moe_mod
 from repro.models.moe_ep import moe_layer_ep
 
@@ -27,7 +26,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model), jnp.float32) *
 # reference: single-device einsum dispatch, ample capacity
 y_ref = moe_mod.moe_layer(params, x, cfg, capacity=64)
 
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     # logical EP mode (training path) — scatter and grouped bodies
     for disp in ("scatter", "grouped"):
         y_ep = jax.jit(lambda p, xx: moe_layer_ep(

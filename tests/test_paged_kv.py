@@ -428,7 +428,7 @@ def test_serve_cli_kv_page_size(monkeypatch, capsys):
 
     monkeypatch.setattr(
         sys, "argv",
-        ["serve", "--arch", "phi4-mini-3.8b", "--scheduler", "none",
+        ["serve", "--arch", "phi4-mini-3.8b-reduced", "--scheduler", "none",
          "--rate", "50", "--duration", "0.04", "--max-batch", "2",
          "--cache-len", "64", "--kv-page-size", "16"],
     )

@@ -481,7 +481,7 @@ def test_serve_cli_prefix_cache(monkeypatch, capsys):
 
     monkeypatch.setattr(
         sys, "argv",
-        ["serve", "--arch", "phi4-mini-3.8b", "--scheduler", "none",
+        ["serve", "--arch", "phi4-mini-3.8b-reduced", "--scheduler", "none",
          "--rate", "40", "--duration", "0.1", "--max-batch", "2",
          "--cache-len", "128", "--kv-page-size", "16", "--prefix-cache",
          "--prefix-cache-pages", "32", "--prefill-batch", "2",
