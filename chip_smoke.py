@@ -183,7 +183,8 @@ def kernels(cfg) -> None:
     check(same, "aebs kernel disagrees with its reference (integer outputs must match exactly)")
 
 
-def serve_args(executor: str, n_instances: int):
+def build_engine(executor: str, n_instances: int, params):
+    """The engine ``repro.launch.serve`` would build from these flags."""
     from repro.launch import serve
 
     argv = [
@@ -192,7 +193,7 @@ def serve_args(executor: str, n_instances: int):
         "--max-batch", str(MAX_BATCH), "--n-instances", str(n_instances),
         "--executor", executor, "--n-attn", "1",
     ]
-    return serve.parse_args(argv)
+    return serve.build_engine(serve.parse_args(argv), params)
 
 
 def make_requests(cfg):
@@ -235,12 +236,6 @@ class LogitProbe:
             return logits, tel
 
         eng._decode_once = probe
-
-
-def build_engine(executor: str, n_instances: int, params):
-    from repro.launch import serve
-
-    return serve.build_engine(serve_args(executor, n_instances), params)
 
 
 def serve_once(tag: str, eng, cfg, check_kernel: bool):

@@ -1,8 +1,9 @@
 """Jit'd public wrapper for the AEBS kernel.
 
 On a TPU the kernel compiles to Mosaic; on any other backend it runs in the
-Pallas interpreter (``repro.kernels.resolve_interpret``).  The wrapper handles padding and exposes the
-same (slot_ids, load, act_rep) contract as ``repro.core.aebs.aebs_assign``.
+Pallas interpreter (``repro.kernels.resolve_interpret``).  The wrapper
+handles padding and exposes the same (slot_ids, load, act_rep) contract as
+``repro.core.aebs.aebs_assign``.
 """
 
 from __future__ import annotations
@@ -11,7 +12,6 @@ import functools
 from typing import Dict, Tuple
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels.aebs.kernel import aebs_pallas
 

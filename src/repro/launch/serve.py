@@ -12,8 +12,6 @@ from __future__ import annotations
 
 import argparse
 
-import numpy as np
-
 
 def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
