@@ -10,14 +10,11 @@ Measures one continuous-batching decode step of ``dsv2-lite-reduced`` across
 * ``disagg_pingpong`` — the same executor with m=2 micro-batch ping-pong
   (attention of micro-batch i overlapped with MoE of micro-batch i+1).
 
-Because forced-host CPU "devices" share one execution queue, the wall clock
-cannot express cross-pool overlap; the overlap figure is therefore composed
-from the *measured per-stage times* (each stage timed with barriers): the
-pipelined step is bounded by the busier pool plus hand-off sync, which is
-exactly the §6 pipeline model — the analytic prediction from
+Forced-host CPU "devices" share one execution queue, so the wall clock
+cannot express cross-pool overlap; the analytic prediction from
 ``benchmarks.sec6_pipelining.pipeline_times`` is printed next to every
-measured row.  On genuinely disjoint hardware the wall clock converges to
-the composed bound.
+measured row.  The executor's stages are ``janus.split.*`` profiler spans:
+a trace of the step shows them beside the device work.
 
 Run:  PYTHONPATH=src python -m benchmarks.disagg_pipeline_bench
 """
@@ -39,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import Row, paper_perf_model, timeit
-from benchmarks.sec6_pipelining import SYNC, pipeline_times
+from benchmarks.sec6_pipelining import pipeline_times
 from repro.configs import get_config
 from repro.core.aebs import ReplicaLayout
 from repro.models import model as model_mod
@@ -103,12 +100,7 @@ def run_sweeps(repeat: int = 5) -> Dict:
             lambda: jax.block_until_ready(ex_seq.decode_step(tokens, positions)[0]),
             repeat=repeat, warmup=2,
         )
-        st: Dict[str, float] = {}
-        n_meas = max(2, repeat - 1)
-        for _ in range(n_meas):
-            _, tel = ex_seq.decode_step(tokens, positions, collect_stage_times=True)
-            for kk, vv in tel["stage_times"].items():
-                st[kk] = st.get(kk, 0.0) + vv / n_meas
+        _, tel = ex_seq.decode_step(tokens, positions)
 
         ex_pp = make(True)
         pp_us = timeit(
@@ -116,37 +108,18 @@ def run_sweeps(repeat: int = 5) -> Dict:
             repeat=repeat, warmup=2,
         )
 
-        # overlap-composed pipelined step from the measured sequential stage
-        # times: with m=2 ping-pong the attention pool runs attention +
-        # exchange + combine while the MoE pool runs the expert stages, so on
-        # disjoint pools the step is bounded by the busier pool plus the
-        # per-micro-batch hand-off sync and the (unoverlapped) head.
-        n_layers = cfg.num_layers
-        attn_pool = st["attn"] + st["exchange"] + st["combine"]
-        moe_pool = st["moe"]
-        pipelined = max(attn_pool, moe_pool) + st["head"] + 2 * n_layers * SYNC
-        sequential = st["attn"] + st["exchange"] + st["moe"] + st["combine"] + st["head"]
-
         t_seq_pred, pipes_pred = pipeline_times(pm, B, n_a, n_e, ms=(2,))
         entry = {
             "arch": ARCH, "batch": B, "n_attn": n_a, "n_moe": n_e,
             "mono_step_ms": round(mono_us / 1e3, 3),
             "disagg_step_ms": round(seq_us / 1e3, 3),
             "disagg_pingpong_wall_ms": round(pp_us / 1e3, 3),
-            "disagg_stage_ms": {k: round(v * 1e3, 3) for k, v in st.items()},
-            "disagg_composed_ms": round(sequential * 1e3, 3),
-            "pingpong_composed_ms": round(pipelined * 1e3, 3),
-            "pingpong_overlap_gain": round(1.0 - pipelined / max(sequential, 1e-12), 3),
             "regime": tel["regime"],
             "transfer_bytes_per_step": tel["bytes_total"],
             "analytic_paper_scale": {
                 "t_seq_us": round(t_seq_pred * 1e6, 1),
                 "t_pipe_m2_us": round(pipes_pred[2] * 1e6, 1),
             },
-            # require a material margin (>5%) so the gate can actually fail
-            # when pool work becomes too imbalanced or sync overhead grows —
-            # max(a,b) < a+b alone would be a tautology
-            "pingpong_beats_sequential": bool(pipelined < 0.95 * sequential),
         }
         results.append(entry)
     return {
@@ -154,9 +127,8 @@ def run_sweeps(repeat: int = 5) -> Dict:
         "backend": jax.default_backend(),
         "devices": len(jax.devices()),
         "notes": "wall times on shared-core forced-host devices serialise "
-                 "cross-pool work; *_composed_ms compose measured per-stage "
-                 "times into the two-pool schedule (the §6 pipeline bound); "
-                 "ample capacity so all three modes emit identical tokens",
+                 "cross-pool work; ample capacity so all three modes emit "
+                 "identical tokens",
         "sweeps": results,
     }
 
@@ -172,8 +144,8 @@ def run() -> List[Row]:
             (
                 f"disagg_pipeline/B{e['batch']}_a{e['n_attn']}e{e['n_moe']}",
                 e["disagg_step_ms"] * 1e3,
-                f"mono={e['mono_step_ms']}ms seq={e['disagg_composed_ms']}ms "
-                f"pp={e['pingpong_composed_ms']}ms ({e['regime']}) "
+                f"mono={e['mono_step_ms']}ms pp={e['disagg_pingpong_wall_ms']}ms "
+                f"({e['regime']}) "
                 f"analytic_seq={e['analytic_paper_scale']['t_seq_us']}us",
             )
         )
@@ -189,9 +161,7 @@ def main() -> None:
         print(
             f"B={e['batch']} {e['n_attn']}A{e['n_moe']}E [{e['regime']}]: "
             f"mono={e['mono_step_ms']}ms disagg={e['disagg_step_ms']}ms "
-            f"pp_wall={e['disagg_pingpong_wall_ms']}ms | composed seq="
-            f"{e['disagg_composed_ms']}ms pp={e['pingpong_composed_ms']}ms "
-            f"(gain {e['pingpong_overlap_gain']:.0%}) | §6 analytic "
+            f"pp_wall={e['disagg_pingpong_wall_ms']}ms | §6 analytic "
             f"seq={e['analytic_paper_scale']['t_seq_us']}us "
             f"pipe={e['analytic_paper_scale']['t_pipe_m2_us']}us"
         )

@@ -37,12 +37,12 @@ in-flight requests continue undisturbed.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.aebs import ReplicaLayout, aebs_assign
 from repro.core.comm import CommConfig, HardwareSpec, adaptive_two_phase, hardware_for
@@ -1009,25 +1009,24 @@ class DisaggExecutor:
     # ------------------------------------------------------------------
     # decode
     # ------------------------------------------------------------------
-    def decode_step(
-        self, tokens, positions, collect_stage_times: bool = False
-    ) -> Tuple[jax.Array, Dict]:
+    def decode_step(self, tokens, positions) -> Tuple[jax.Array, Dict]:
         """One batched decode step.  Returns (logits [b, vocab], telemetry)."""
-        return self._decode_impl(tokens, positions, None, collect_stage_times)
+        return self._decode_impl(tokens, positions, None)
 
-    def decode_step_verify(
-        self, tokens, positions, widths, collect_stage_times: bool = False
-    ) -> Tuple[jax.Array, Dict]:
+    def decode_step_verify(self, tokens, positions, widths) -> Tuple[jax.Array, Dict]:
         """One batched speculative-verify step: ``tokens`` is [b, c] (last
         accepted token + drafts), ``widths`` the per-slot valid row counts.
         Returns (logits [b, c, vocab], telemetry).  Each per-layer exchange
         ships c rows per slot instead of one, so the transfer-bytes telemetry
         directly shows the amortisation speculation buys."""
-        return self._decode_impl(tokens, positions, widths, collect_stage_times)
+        return self._decode_impl(tokens, positions, widths)
 
-    def _decode_impl(
-        self, tokens, positions, widths, collect_stage_times: bool = False
-    ) -> Tuple[jax.Array, Dict]:
+    def _decode_impl(self, tokens, positions, widths) -> Tuple[jax.Array, Dict]:
+        """The step's stages are profiler spans as the host dispatches them:
+        ``janus.split.{attn,exchange,moe,combine}`` per layer (``layer``) and
+        micro-batch (``mb``), then ``janus.split.head``.  Dispatch is
+        asynchronous, so a span ends when its work is queued, not done; the
+        device trace shows when it ran."""
         verify = widths is not None
         self._sync_tables()
         cfg = self.cfg
@@ -1040,22 +1039,14 @@ class DisaggExecutor:
             batch=self.max_batch,
             hw=dataclasses.replace(self.hw, devices_per_node=max(1, pools.node_size)),
         )
-        t_pred, regime = adaptive_two_phase(c)
+        _, regime = adaptive_two_phase(c)
         tel: Dict = {
             "regime": regime,
-            "t_comm_pred": t_pred,
             "bytes_slow": 0,
             "bytes_fast": 0,
             "msgs_slow": 0,
             "msgs_fast": 0,
         }
-        times: Dict[str, float] = {"attn": 0.0, "exchange": 0.0, "moe": 0.0, "combine": 0.0}
-
-        def _tick(key, arrs, t0):
-            if collect_stage_times:
-                jax.block_until_ready(arrs)
-                times[key] += time.perf_counter() - t0
-            return time.perf_counter()
 
         # shard inputs + embed (attention pool)
         xs: List[jax.Array] = []
@@ -1090,26 +1081,25 @@ class DisaggExecutor:
         for li, (per, pos_idx, kind, cidx) in enumerate(self._layers):
             h2s_all: List[Optional[jax.Array]] = [None] * len(self.shards)
 
-            def attn_mb(group, li=li, cidx=cidx):
-                t0 = time.perf_counter()
-                for si in group:
-                    s = self.shards[si]
-                    lp = self._attn_params[s.dev_index]["layers"][li]
-                    if verify:
-                        x, h2, new_kv = self._attn_verify_jit(
-                            lp, xs[si], self._kv[si][cidx], poss[si], wids[si]
-                        )
-                    else:
-                        x, h2, new_kv = self._attn_jit(
-                            lp, xs[si], self._kv[si][cidx], poss[si]
-                        )
-                    xs[si], h2s_all[si] = x, h2
-                    self._kv[si][cidx] = new_kv
-                _tick("attn", [xs[si] for si in group], t0)
+            def attn_mb(m, group, li=li, cidx=cidx):
+                with TraceAnnotation("janus.split.attn", layer=li, mb=m):
+                    for si in group:
+                        s = self.shards[si]
+                        lp = self._attn_params[s.dev_index]["layers"][li]
+                        if verify:
+                            x, h2, new_kv = self._attn_verify_jit(
+                                lp, xs[si], self._kv[si][cidx], poss[si], wids[si]
+                            )
+                        else:
+                            x, h2, new_kv = self._attn_jit(
+                                lp, xs[si], self._kv[si][cidx], poss[si]
+                            )
+                        xs[si], h2s_all[si] = x, h2
+                        self._kv[si][cidx] = new_kv
 
             if kind == "dense":
-                for group in mbs:
-                    attn_mb(group)
+                for m, group in enumerate(mbs):
+                    attn_mb(m, group)
                     for si in group:
                         lp = self._attn_params[self.shards[si].dev_index]["layers"][li]
                         xs[si] = self._dense_jit(lp, xs[si], h2s_all[si])
@@ -1123,92 +1113,81 @@ class DisaggExecutor:
             pending: List[Tuple[int, List[int], List]] = []
             moe_jit = self._moe_verify_jit if verify else self._moe_jit
             for m, group in enumerate(mbs):
-                attn_mb(group)
-                t0 = time.perf_counter()
-                h2s = {self.shards[si].dev_index: h2s_all[si] for si in group}
-                if self.fault_hook is not None:
-                    self.fault_hook("exchange", li, m)
-                h_on_moe = self._run_exchange(h2s, regime, tel)
-                t0 = _tick("exchange", h_on_moe, t0)
-                res = [
-                    moe_jit(
-                        self._moe_params[g]["layers"][li],
-                        self._moe_params[g]["tables"],
-                        self._moe_params[g]["lo"],
-                        h_on_moe[g],
-                    )
-                    for g in range(self.n_moe)
-                ]
-                _tick("moe", [r[0] for r in res], t0)
+                attn_mb(m, group)
+                with TraceAnnotation("janus.split.exchange", layer=li, mb=m):
+                    h2s = {self.shards[si].dev_index: h2s_all[si] for si in group}
+                    if self.fault_hook is not None:
+                        self.fault_hook("exchange", li, m)
+                    h_on_moe = self._run_exchange(h2s, regime, tel)
+                with TraceAnnotation("janus.split.moe", layer=li, mb=m):
+                    res = [
+                        moe_jit(
+                            self._moe_params[g]["layers"][li],
+                            self._moe_params[g]["tables"],
+                            self._moe_params[g]["lo"],
+                            h_on_moe[g],
+                        )
+                        for g in range(self.n_moe)
+                    ]
                 if pending:
                     self._combine_mb(
-                        *pending.pop(0), xs, h2s_all, offs, li, tel, times,
-                        collect_stage_times, amax_parts, verify,
+                        *pending.pop(0), xs, h2s_all, offs, li, tel, amax_parts, verify
                     )
                 pending.append((m, group, res))
             while pending:
                 self._combine_mb(
-                    *pending.pop(0), xs, h2s_all, offs, li, tel, times,
-                    collect_stage_times, amax_parts, verify,
+                    *pending.pop(0), xs, h2s_all, offs, li, tel, amax_parts, verify
                 )
 
-        t0 = time.perf_counter()
-        head_jit = self._head_verify_jit if verify else self._head_jit
-        logit_shards = {}
-        for si, s in enumerate(self.shards):
-            p = self._attn_params[s.dev_index]
-            logit_shards[s.lo] = head_jit(
-                {"final_norm": p["final_norm"], "embed": p["embed"]}, xs[si]
+        with TraceAnnotation("janus.split.head"):
+            head_jit = self._head_verify_jit if verify else self._head_jit
+            logit_shards = {}
+            for si, s in enumerate(self.shards):
+                p = self._attn_params[s.dev_index]
+                logit_shards[s.lo] = head_jit(
+                    {"final_norm": p["final_norm"], "embed": p["embed"]}, xs[si]
+                )
+            logits = jnp.concatenate(
+                [
+                    jax.device_put(logit_shards[lo], pools.attn_devices[0])
+                    for lo in sorted(logit_shards)
+                ],
+                axis=0,
             )
-        logits = jnp.concatenate(
-            [
-                jax.device_put(logit_shards[lo], pools.attn_devices[0])
-                for lo in sorted(logit_shards)
-            ],
-            axis=0,
-        )
-        if collect_stage_times:
-            logits.block_until_ready()
-            times["head"] = time.perf_counter() - t0
-            tel["stage_times"] = times
         tel["a_max"] = int(np.max([np.asarray(a) for a in amax_parts])) if amax_parts else 0
         tel["bytes_total"] = tel["bytes_slow"] + tel["bytes_fast"]
         return logits, tel
 
     def _combine_mb(
-        self, m, group, res, xs, h2s_all, offs, li, tel, times, collect,
-        amax_parts, verify=False,
+        self, m, group, res, xs, h2s_all, offs, li, tel, amax_parts, verify=False
     ) -> None:
         """Ship expert partials back to the owning attention shards and run
         the gate-combine there (mono-identical op order).  In verify mode a
         shard's rows carry ``c`` candidate tokens each, so item/gate slices
         scale by the per-row token width."""
-        t0 = time.perf_counter()
-        k = self.cfg.top_k
-        # per-row token width: 1 in decode, c (candidate rows) in verify
-        w = xs[group[0]].shape[1] if verify else 1
-        off, _total = offs[m]
-        amax_parts.append(jnp.max(res[0][4]))  # load from instance 0 (redundant copies agree)
-        for si in group:
-            s = self.shards[si]
-            dev = self.pools.attn_devices[s.dev_index]
-            r0, r1 = off[si] * w, (off[si] + s.rows) * w
-            parts = []
-            for y_items, keep, local, _gates, _load in res:
-                part = (
-                    jax.device_put(y_items[r0 * k : r1 * k], dev),
-                    jax.device_put(keep[r0 * k : r1 * k], dev),
-                    jax.device_put(local[r0 * k : r1 * k], dev),
-                )
-                tel["bytes_slow"] += sum(a.nbytes for a in part)
+        with TraceAnnotation("janus.split.combine", layer=li, mb=m):
+            k = self.cfg.top_k
+            # per-row token width: 1 in decode, c (candidate rows) in verify
+            w = xs[group[0]].shape[1] if verify else 1
+            off, _total = offs[m]
+            amax_parts.append(jnp.max(res[0][4]))  # load from instance 0 (redundant copies agree)
+            for si in group:
+                s = self.shards[si]
+                dev = self.pools.attn_devices[s.dev_index]
+                r0, r1 = off[si] * w, (off[si] + s.rows) * w
+                parts = []
+                for y_items, keep, local, _gates, _load in res:
+                    part = (
+                        jax.device_put(y_items[r0 * k : r1 * k], dev),
+                        jax.device_put(keep[r0 * k : r1 * k], dev),
+                        jax.device_put(local[r0 * k : r1 * k], dev),
+                    )
+                    tel["bytes_slow"] += sum(a.nbytes for a in part)
+                    tel["msgs_slow"] += 1
+                    parts.append(part)
+                gates = jax.device_put(res[0][3][r0:r1], dev)
+                tel["bytes_slow"] += gates.nbytes
                 tel["msgs_slow"] += 1
-                parts.append(part)
-            gates = jax.device_put(res[0][3][r0:r1], dev)
-            tel["bytes_slow"] += gates.nbytes
-            tel["msgs_slow"] += 1
-            shared = self._attn_params[s.dev_index]["shared"][li]
-            combine = self._combine_verify_jit if verify else self._combine_jit
-            xs[si] = combine(xs[si], h2s_all[si], shared, parts, gates)
-        if collect:
-            jax.block_until_ready([xs[si] for si in group])
-            times["combine"] += time.perf_counter() - t0
+                shared = self._attn_params[s.dev_index]["shared"][li]
+                combine = self._combine_verify_jit if verify else self._combine_jit
+                xs[si] = combine(xs[si], h2s_all[si], shared, parts, gates)

@@ -43,7 +43,14 @@ The engine serves a stream of requests against one model deployment:
     decode clock;
   * scaling: :meth:`ServingEngine.reconfigure` actuates a controller
     decision mid-run (§3.5) — prefill, attention and MoE pool counts move
-    independently, in-flight KV caches are preserved.
+    independently, in-flight KV caches are preserved;
+  * spans: each decode iteration, admission, prefill chunk and KV hand-off
+    is a ``jax.profiler.TraceAnnotation`` named ``janus.*`` (counts as its
+    keyword arguments), on the device trace's clock when a profiler runs
+    and ~1 µs each when none does.  A decode iteration is ``janus.decode``
+    with the children ``.prepare`` (pages, uploads), ``.device`` (the step
+    until its logits are ready), ``.sample`` (token readback) and
+    ``.advance`` (per-slot bookkeeping); no span is opened per slot.
 """
 
 from __future__ import annotations
@@ -55,6 +62,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.aebs import ReplicaLayout, aebs_assign
 from repro.core import baselines
@@ -999,77 +1007,84 @@ class ServingEngine:
         """Blocking admission: drain the prefill worker synchronously for this
         one request — the legacy schedule (the decode loop stalls for the
         whole prompt), with the same chunked compute as the pipelined path."""
-        stalled = self.slots.num_active > 0
-        slot = self.slots.reserve(req)
-        self.slots.start_prefill(slot)
-        now = max(self.clock, req.arrival)
-        start, seed = self._prefix_splice(req, slot)
-        self.prefill_worker.submit(req, slot, now=now, start=start, seed_caches=seed)
-        events: List[PrefillEvent] = []
-        while not events:
-            events = self._worker_poll()
-        ev = events[0]
-        # legacy clock semantics: modeled prefill time when calibrated, wall
-        # otherwise (zero under a modeled decode clock with no prefill model —
-        # the worker's time_fn is already pinned to 0 for that combination)
-        dt = ev.finish_t - now
-        self.slots.activate(slot)
-        self.tokens = self.tokens.at[slot, 0].set(ev.first_token)
-        self.clock += dt
-        if stalled:
-            self.decode_stall_time += dt
-        req.prefill_done = self.clock
-        req.token_times.append(self.clock)
-        req.tokens_out = [ev.first_token]
-        self._prefix_publish(req, slot)
+        with TraceAnnotation("janus.admit", rid=req.rid, prompt_len=req.input_len) as span:
+            stalled = self.slots.num_active > 0
+            slot = self.slots.reserve(req)
+            span.set_metadata(slot=slot)
+            self.slots.start_prefill(slot)
+            now = max(self.clock, req.arrival)
+            start, seed = self._prefix_splice(req, slot)
+            self.prefill_worker.submit(req, slot, now=now, start=start, seed_caches=seed)
+            events: List[PrefillEvent] = []
+            while not events:
+                events = self._worker_poll()
+            ev = events[0]
+            # legacy clock semantics: modeled prefill time when calibrated, wall
+            # otherwise (zero under a modeled decode clock with no prefill model —
+            # the worker's time_fn is already pinned to 0 for that combination)
+            dt = ev.finish_t - now
+            self.slots.activate(slot)
+            self.tokens = self.tokens.at[slot, 0].set(ev.first_token)
+            self.clock += dt
+            if stalled:
+                self.decode_stall_time += dt
+            req.prefill_done = self.clock
+            req.token_times.append(self.clock)
+            req.tokens_out = [ev.first_token]
+            self._prefix_publish(req, slot)
 
     def _submit_request(self, req: Request) -> None:
         """Pipelined admission: reserve the slot, queue the prompt for the
         prefill pool — the decode clock is never charged."""
-        slot = self.slots.reserve(req)
-        self.slots.start_prefill(slot)
-        start, seed = self._prefix_splice(req, slot)
-        self.prefill_worker.submit(
-            req, slot, now=max(self.clock, req.arrival),
-            start=start, seed_caches=seed,
-        )
+        with TraceAnnotation("janus.admit", rid=req.rid, prompt_len=req.input_len) as span:
+            slot = self.slots.reserve(req)
+            span.set_metadata(slot=slot)
+            self.slots.start_prefill(slot)
+            start, seed = self._prefix_splice(req, slot)
+            self.prefill_worker.submit(
+                req, slot, now=max(self.clock, req.arrival),
+                start=start, seed_caches=seed,
+            )
 
     def _chunk_sink(self, slot: int, start: int, length: int, one_caches: Dict) -> None:
         """Land one streamed prefill chunk (or a whole-prompt fallback cache,
         ``length == -1``) in the decode-side caches."""
-        if self.disagg is not None:
-            if length < 0:
-                self.disagg.scatter_prefill(one_caches, slot)
+        with TraceAnnotation("janus.prefill.handoff", slot=slot, lo=start, n=length):
+            if self.disagg is not None:
+                if length < 0:
+                    self.disagg.scatter_prefill(one_caches, slot)
+                else:
+                    self.disagg.scatter_prefill_chunk(one_caches, slot, start, length)
+            elif self.paged is not None:
+                if length < 0:
+                    # whole-prompt fallback: the prompt's rows are one big chunk;
+                    # positionless state (ssm/enc_out) takes the contiguous path
+                    start, length = 0, self.slots.slot_req[slot].input_len
+                    rest = {
+                        k: v for k, v in one_caches.items() if not k.startswith("kv_")
+                    }
+                    if rest:
+                        self.caches = scatter_prefill_caches(self.caches, rest, slot)
+                self.caches = scatter_prefill_chunk_paged(
+                    self.caches, one_caches, slot, start, length, self.paged
+                )
+            elif length < 0:
+                self.caches = scatter_prefill_caches(self.caches, one_caches, slot)
             else:
-                self.disagg.scatter_prefill_chunk(one_caches, slot, start, length)
-        elif self.paged is not None:
-            if length < 0:
-                # whole-prompt fallback: the prompt's rows are one big chunk;
-                # positionless state (ssm/enc_out) takes the contiguous path
-                start, length = 0, self.slots.slot_req[slot].input_len
-                rest = {
-                    k: v for k, v in one_caches.items() if not k.startswith("kv_")
-                }
-                if rest:
-                    self.caches = scatter_prefill_caches(self.caches, rest, slot)
-            self.caches = scatter_prefill_chunk_paged(
-                self.caches, one_caches, slot, start, length, self.paged
-            )
-        elif length < 0:
-            self.caches = scatter_prefill_caches(self.caches, one_caches, slot)
-        else:
-            self.caches = scatter_prefill_chunk_caches(
-                self.caches, one_caches, slot, start, length
-            )
+                self.caches = scatter_prefill_chunk_caches(
+                    self.caches, one_caches, slot, start, length
+                )
 
     # ------------------------------------------------------------------
     # paged-KV slot lifecycle
     # ------------------------------------------------------------------
-    def _ensure_pages(self, widths=None) -> None:
+    def _ensure_pages(self, widths=None) -> bool:
         """Back every active slot's next write position with a page (alloc on
         append) and refresh the device block table if anything changed.  A
         speculative verify writes ``widths[s]`` rows starting at the slot's
-        position, so its whole candidate span is backed up front."""
+        position, so its whole candidate span is backed up front.  Returns
+        whether the mono block table went to the device again (the disagg
+        executor uploads its shards' tables inside its step)."""
         if self.paged is not None:
             for s in self.slots.active_slots:
                 extent = int(widths[s]) - 1 if widths is not None else 0
@@ -1077,12 +1092,14 @@ class ServingEngine:
             if self.paged.dirty:
                 self.caches = dict(self.caches)
                 self.caches["block_tables"] = self.paged.table_device()
+                return True
         elif self.disagg is not None:
             for s in self.slots.active_slots:
                 extent = int(widths[s]) - 1 if widths is not None else 0
                 self.disagg.ensure_slot_pages(
                     s, int(self.slots.positions[s]) + extent
                 )
+        return False
 
     def _ensure_slot_page(self, slot: int, pos: int) -> None:
         """Replay-path variant of :meth:`_ensure_pages` for a single slot."""
@@ -1174,7 +1191,8 @@ class ServingEngine:
             self._fault_preflight()
         active = list(self.slots.active_slots)
         widths = self._spec_widths()
-        self._ensure_pages(widths)
+        with TraceAnnotation("janus.decode.prepare") as span:
+            span.set_metadata(dirty=self._ensure_pages(widths))
         for s in active:
             self._draft_ensure(s)
         t0 = time.perf_counter()
@@ -1196,9 +1214,10 @@ class ServingEngine:
         vtokens[:, 0] = np.asarray(self.tokens[:, 0])
         vtokens[:, 1:] = drafts
         positions = self.slots.positions_device()
-        logits, tel = self._guarded_decode(
-            positions, spec=(jnp.asarray(vtokens), jnp.asarray(widths))
-        )
+        with TraceAnnotation("janus.decode.device"):
+            logits, tel = self._guarded_decode(
+                positions, spec=(jnp.asarray(vtokens), jnp.asarray(widths))
+            )
         if tel is not None:
             self.regime_log.append(tel["regime"])
             self.transfer_bytes_log.append(tel["bytes_total"])
@@ -1210,81 +1229,94 @@ class ServingEngine:
         self.steps_done += 1
         self.spec_steps += 1
 
-        greedy = np.asarray(jnp.argmax(logits, axis=-1), np.int32)  # [b, c]
-        new = self.tokens
-        for s in active:
-            if self.slots.state[s] != ACTIVE:
-                continue  # released by a recovery path mid-iteration
-            req = self.slots.slot_req[s]
-            w = int(widths[s])
-            a = 0
-            while a < w - 1 and drafts[s, a] == greedy[s, a]:
-                a += 1
-            gained = a + 1
-            self.spec_slot_steps += 1
-            self.spec_draft_tokens += w - 1
-            self.spec_draft_accepted += a
-            self.spec_emitted_tokens += gained
-            for j in range(gained):
-                req.generated += 1
-                req.token_times.append(self.clock)
-                self.slots.advance(s)
-                if req.tokens_out is not None:
-                    req.tokens_out.append(int(greedy[s, j]))
-            new = new.at[s, 0].set(int(greedy[s, a]))
-            pos = int(self.slots.positions[s])
-            # verify backed w rows but only `gained` advanced: clamp the
-            # high-water mark so spill records and occupancy stay honest
-            if self.paged is not None:
-                self.paged.truncate(s, pos)
-            elif self.disagg is not None:
-                self.disagg.truncate_slot(s, pos)
-            self._draft_stream[s] = (req.rid, pos)
-            if req.generated >= req.output_len or pos >= self.cache_len - 2:
-                if req.generated < req.output_len:
-                    req.truncated = True  # context exhausted before target
-                req.finished = self.clock
-                self.completed.append(self.slots.release(s))
-                self._release_pages(s)
-                self._draft_stream.pop(s, None)
-        self.tokens = new
+        with TraceAnnotation("janus.decode.sample"):
+            greedy = np.asarray(jnp.argmax(logits, axis=-1), np.int32)  # [b, c]
+        with TraceAnnotation("janus.decode.advance") as span:
+            done = len(self.completed)
+            new = self.tokens
+            for s in active:
+                if self.slots.state[s] != ACTIVE:
+                    continue  # released by a recovery path mid-iteration
+                req = self.slots.slot_req[s]
+                w = int(widths[s])
+                a = 0
+                while a < w - 1 and drafts[s, a] == greedy[s, a]:
+                    a += 1
+                gained = a + 1
+                self.spec_slot_steps += 1
+                self.spec_draft_tokens += w - 1
+                self.spec_draft_accepted += a
+                self.spec_emitted_tokens += gained
+                for j in range(gained):
+                    req.generated += 1
+                    req.token_times.append(self.clock)
+                    self.slots.advance(s)
+                    if req.tokens_out is not None:
+                        req.tokens_out.append(int(greedy[s, j]))
+                new = new.at[s, 0].set(int(greedy[s, a]))
+                pos = int(self.slots.positions[s])
+                # verify backed w rows but only `gained` advanced: clamp the
+                # high-water mark so spill records and occupancy stay honest
+                if self.paged is not None:
+                    self.paged.truncate(s, pos)
+                elif self.disagg is not None:
+                    self.disagg.truncate_slot(s, pos)
+                self._draft_stream[s] = (req.rid, pos)
+                if req.generated >= req.output_len or pos >= self.cache_len - 2:
+                    if req.generated < req.output_len:
+                        req.truncated = True  # context exhausted before target
+                    req.finished = self.clock
+                    self.completed.append(self.slots.release(s))
+                    self._release_pages(s)
+                    self._draft_stream.pop(s, None)
+            self.tokens = new
+            span.set_metadata(completed=len(self.completed) - done)
 
     # ------------------------------------------------------------------
     def _decode_iteration(self) -> None:
-        if self.spec_k:
-            self._spec_iteration()
-            return
-        if self.faults is not None:
-            self._fault_preflight()
-        self._ensure_pages()
-        positions = self.slots.positions_device()
-        t0 = time.perf_counter()
-        logits, tel = self._guarded_decode(positions)
-        if tel is not None:
-            self.regime_log.append(tel["regime"])
-            self.transfer_bytes_log.append(tel["bytes_total"])
-            self.amax_log.append(tel["a_max"])
-        wall = time.perf_counter() - t0
-        self.clock += self.step_time_fn(self.slots.num_active) if self.step_time_fn else wall
-        self.steps_done += 1
+        with TraceAnnotation(
+            "janus.decode", step=self.steps_done, active=self.slots.num_active
+        ):
+            if self.spec_k:
+                self._spec_iteration()
+                return
+            if self.faults is not None:
+                self._fault_preflight()
+            with TraceAnnotation("janus.decode.prepare") as span:
+                span.set_metadata(dirty=self._ensure_pages())
+                positions = self.slots.positions_device()
+            t0 = time.perf_counter()
+            with TraceAnnotation("janus.decode.device"):
+                logits, tel = self._guarded_decode(positions)
+            if tel is not None:
+                self.regime_log.append(tel["regime"])
+                self.transfer_bytes_log.append(tel["bytes_total"])
+                self.amax_log.append(tel["a_max"])
+            wall = time.perf_counter() - t0
+            self.clock += self.step_time_fn(self.slots.num_active) if self.step_time_fn else wall
+            self.steps_done += 1
 
-        next_tokens = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        new = self.tokens
-        for s in self.slots.active_slots:
-            req = self.slots.slot_req[s]
-            req.generated += 1
-            req.token_times.append(self.clock)
-            self.slots.advance(s)
-            new = new.at[s, 0].set(int(next_tokens[s]))
-            if req.tokens_out is not None:
-                req.tokens_out.append(int(next_tokens[s]))
-            if req.generated >= req.output_len or self.slots.positions[s] >= self.cache_len - 2:
-                if req.generated < req.output_len:
-                    req.truncated = True  # context exhausted before target length
-                req.finished = self.clock
-                self.completed.append(self.slots.release(s))
-                self._release_pages(s)
-        self.tokens = new
+            with TraceAnnotation("janus.decode.sample"):
+                next_tokens = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            with TraceAnnotation("janus.decode.advance") as span:
+                done = len(self.completed)
+                new = self.tokens
+                for s in self.slots.active_slots:
+                    req = self.slots.slot_req[s]
+                    req.generated += 1
+                    req.token_times.append(self.clock)
+                    self.slots.advance(s)
+                    new = new.at[s, 0].set(int(next_tokens[s]))
+                    if req.tokens_out is not None:
+                        req.tokens_out.append(int(next_tokens[s]))
+                    if req.generated >= req.output_len or self.slots.positions[s] >= self.cache_len - 2:
+                        if req.generated < req.output_len:
+                            req.truncated = True  # context exhausted before target length
+                        req.finished = self.clock
+                        self.completed.append(self.slots.release(s))
+                        self._release_pages(s)
+                self.tokens = new
+                span.set_metadata(completed=len(self.completed) - done)
 
     # ------------------------------------------------------------------
     def run(self, requests: List[Request], max_steps: int = 100_000) -> Dict:

@@ -29,6 +29,11 @@ clock — on disjoint hardware the two pools really do overlap; on shared host
 devices the schedule (chunk order, per-device serialisation, completion
 stamps) is the real one even though the arithmetic shares cores.  The engine
 activates a finished request once its clock passes the completion stamp.
+
+Each chunk call, from its dispatch to its logits on the device, is a
+``janus.prefill.chunk`` profiler span carrying the request's ``rid`` (a
+fused call: its ``rows``), the chunk's first position ``lo`` and its token
+count ``n``.
 """
 
 from __future__ import annotations
@@ -40,6 +45,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import model as model_mod
 from repro.serving.request import Request
@@ -345,8 +351,9 @@ class PrefillWorker:
             # the pool device, one bulk hand-off
             toks = jax.device_put(jnp.asarray(entry.prompt)[None, :], dev)
             t0 = time.perf_counter()
-            logits, caches = self._full_jit(params, toks)
-            logits.block_until_ready()
+            with TraceAnnotation("janus.prefill.chunk", rid=entry.req.rid, lo=0, n=n):
+                logits, caches = self._full_jit(params, toks)
+                logits.block_until_ready()
             dt = self.prefill_time_fn(n) if self.prefill_time_fn else time.perf_counter() - t0
             sink(entry.slot, 0, -1, caches)
             return self._finish(entry, logits, dt)
@@ -357,8 +364,9 @@ class PrefillWorker:
             entry.caches = self._init_caches(entry, dev)
         toks = jax.device_put(jnp.asarray(entry.prompt[lo:hi])[None, :], dev)
         t0 = time.perf_counter()
-        logits, entry.caches = self._chunk_jit(params, toks, entry.caches, jnp.int32(lo))
-        logits.block_until_ready()
+        with TraceAnnotation("janus.prefill.chunk", rid=entry.req.rid, lo=lo, n=hi - lo):
+            logits, entry.caches = self._chunk_jit(params, toks, entry.caches, jnp.int32(lo))
+            logits.block_until_ready()
         dt = (
             self.prefill_time_fn(hi - lo)
             if self.prefill_time_fn
@@ -414,10 +422,11 @@ class PrefillWorker:
         toks_d = jax.device_put(jnp.asarray(toks), dev)
         starts = jax.device_put(jnp.asarray(los, jnp.int32), dev)
         lengths = jax.device_put(jnp.asarray(lens, jnp.int32), dev)
-        t0 = time.perf_counter()
-        logits, stacked = self._batch_jit(params, toks_d, stacked, starts, lengths)
-        logits.block_until_ready()
         total = sum(lens)
+        t0 = time.perf_counter()
+        with TraceAnnotation("janus.prefill.chunk", rows=B, lo=min(los), n=total):
+            logits, stacked = self._batch_jit(params, toks_d, stacked, starts, lengths)
+            logits.block_until_ready()
         dt = (
             self.prefill_time_fn(total)
             if self.prefill_time_fn
