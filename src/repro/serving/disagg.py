@@ -1010,7 +1010,9 @@ class DisaggExecutor:
     # decode
     # ------------------------------------------------------------------
     def decode_step(self, tokens, positions) -> Tuple[jax.Array, Dict]:
-        """One batched decode step.  Returns (logits [b, vocab], telemetry)."""
+        """One batched decode step.  Returns (logits [b, vocab], telemetry).
+        ``tokens`` [b, 1] may be a host array: each shard's rows then go
+        straight to that shard's attention device, not through device 0."""
         return self._decode_impl(tokens, positions, None)
 
     def decode_step_verify(self, tokens, positions, widths) -> Tuple[jax.Array, Dict]:
@@ -1054,7 +1056,7 @@ class DisaggExecutor:
         wids: List[Optional[jax.Array]] = []
         for si, s in enumerate(self.shards):
             dev = pools.attn_devices[s.dev_index]
-            tok = jax.device_put(jnp.asarray(tokens)[s.lo : s.hi], dev)
+            tok = jax.device_put(tokens[s.lo : s.hi], dev)
             pos = jax.device_put(jnp.asarray(positions)[s.lo : s.hi], dev)
             poss.append(pos)
             wids.append(

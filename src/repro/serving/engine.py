@@ -172,7 +172,12 @@ class ServingEngine:
         self.prefill_time_fn = prefill_time_fn
         self.extra_builder = extra_builder
         self.executor_name = executor
-        self.tokens = jnp.zeros((max_batch, 1), jnp.int32)
+        # the next decode step's input tokens: written on the host, uploaded
+        # once per iteration by _upload_tokens (the only source of truth)
+        self.tokens = np.zeros((max_batch, 1), np.int32)
+        self._tokens_in = None  # the uploaded copy the decode step reads
+        self._tokens_dirty = True
+        self.token_uploads = 0
         self.clock = 0.0
         self.amax_log: List[int] = []
         self.regime_log: List[str] = []
@@ -597,9 +602,7 @@ class ServingEngine:
             pos[slot] = req.input_len + t
             self._ensure_slot_page(slot, req.input_len + t)
             if self.disagg is not None:
-                logits, _ = self.disagg.decode_step(
-                    jnp.asarray(toks), jnp.asarray(pos)
-                )
+                logits, _ = self.disagg.decode_step(toks, jnp.asarray(pos))
             else:
                 logits, self.caches = self._decode_jit(
                     self.params, jnp.asarray(toks), self.caches, jnp.asarray(pos)
@@ -676,6 +679,25 @@ class ServingEngine:
             self._charge(self.faults.consume_delay())
             return logits, tel
 
+    def _set_token(self, slot: int, token: int) -> None:
+        """Write ``slot``'s next decode input on the host copy."""
+        self.tokens[slot, 0] = token
+        self._tokens_dirty = True
+
+    def _upload_tokens(self) -> bool:
+        """Send the step's input tokens to the executor once, if they changed
+        since the last upload.  The upload is a fresh copy, so no device
+        array aliases the host buffer the loop keeps writing.  Mono puts it
+        on the default device; the disagg executor is handed the host copy
+        and puts each shard's rows on that shard's attention device."""
+        if not self._tokens_dirty:
+            return False
+        snap = self.tokens.copy()
+        self._tokens_in = snap if self.disagg is not None else jax.device_put(snap)
+        self._tokens_dirty = False
+        self.token_uploads += 1
+        return True
+
     def _decode_once(self, positions, spec=None) -> tuple:
         if spec is not None:
             vtokens, widths = spec
@@ -691,11 +713,11 @@ class ServingEngine:
             logits.block_until_ready()
             return logits, None
         if self.disagg is not None:
-            logits, tel = self.disagg.decode_step(self.tokens, positions)
+            logits, tel = self.disagg.decode_step(self._tokens_in, positions)
             logits.block_until_ready()
             return logits, tel
         logits, self.caches = self._decode_jit(
-            self.params, self.tokens, self.caches, positions
+            self.params, self._tokens_in, self.caches, positions
         )
         logits.block_until_ready()
         return logits, None
@@ -913,7 +935,7 @@ class ServingEngine:
             else:
                 self.disagg.restore_slot(slot, rec.payload)
             self.slots.resume(slot)
-        self.tokens = self.tokens.at[slot, 0].set(req.tokens_out[-1])
+        self._set_token(slot, req.tokens_out[-1])
         # the park time between two of the request's tokens is scheduling
         # wait, not decode latency — record it so TPOT can split it out
         if req.wait_spans is None:
@@ -1024,7 +1046,7 @@ class ServingEngine:
             # the worker's time_fn is already pinned to 0 for that combination)
             dt = ev.finish_t - now
             self.slots.activate(slot)
-            self.tokens = self.tokens.at[slot, 0].set(ev.first_token)
+            self._set_token(slot, ev.first_token)
             self.clock += dt
             if stalled:
                 self.decode_stall_time += dt
@@ -1126,7 +1148,7 @@ class ServingEngine:
         for ev in self._ready:
             if ev.finish_t <= self.clock:
                 self.slots.activate(ev.slot)
-                self.tokens = self.tokens.at[ev.slot, 0].set(ev.first_token)
+                self._set_token(ev.slot, ev.first_token)
                 ev.req.prefill_done = ev.finish_t
                 ev.req.token_times.append(ev.finish_t)
                 ev.req.tokens_out = [ev.first_token]
@@ -1192,13 +1214,15 @@ class ServingEngine:
         active = list(self.slots.active_slots)
         widths = self._spec_widths()
         with TraceAnnotation("janus.decode.prepare") as span:
-            span.set_metadata(dirty=self._ensure_pages(widths))
+            span.set_metadata(
+                dirty=self._ensure_pages(widths), tokens=int(self._upload_tokens())
+            )
         for s in active:
             self._draft_ensure(s)
         t0 = time.perf_counter()
         c = self.spec_k + 1
         drafts = np.zeros((self.max_batch, self.spec_k), np.int32)
-        feed = self.tokens
+        feed = self._tokens_in
         for j in range(c):
             dpos = jnp.asarray(
                 np.minimum(self.slots.positions + j, self.cache_len - 1)
@@ -1211,7 +1235,7 @@ class ServingEngine:
                 drafts[:, j] = nxt
                 feed = jnp.asarray(nxt[:, None])
         vtokens = np.zeros((self.max_batch, c), np.int32)
-        vtokens[:, 0] = np.asarray(self.tokens[:, 0])
+        vtokens[:, 0] = self.tokens[:, 0]
         vtokens[:, 1:] = drafts
         positions = self.slots.positions_device()
         with TraceAnnotation("janus.decode.device"):
@@ -1233,7 +1257,6 @@ class ServingEngine:
             greedy = np.asarray(jnp.argmax(logits, axis=-1), np.int32)  # [b, c]
         with TraceAnnotation("janus.decode.advance") as span:
             done = len(self.completed)
-            new = self.tokens
             for s in active:
                 if self.slots.state[s] != ACTIVE:
                     continue  # released by a recovery path mid-iteration
@@ -1253,7 +1276,7 @@ class ServingEngine:
                     self.slots.advance(s)
                     if req.tokens_out is not None:
                         req.tokens_out.append(int(greedy[s, j]))
-                new = new.at[s, 0].set(int(greedy[s, a]))
+                self._set_token(s, greedy[s, a])
                 pos = int(self.slots.positions[s])
                 # verify backed w rows but only `gained` advanced: clamp the
                 # high-water mark so spill records and occupancy stay honest
@@ -1269,7 +1292,6 @@ class ServingEngine:
                     self.completed.append(self.slots.release(s))
                     self._release_pages(s)
                     self._draft_stream.pop(s, None)
-            self.tokens = new
             span.set_metadata(completed=len(self.completed) - done)
 
     # ------------------------------------------------------------------
@@ -1283,7 +1305,9 @@ class ServingEngine:
             if self.faults is not None:
                 self._fault_preflight()
             with TraceAnnotation("janus.decode.prepare") as span:
-                span.set_metadata(dirty=self._ensure_pages())
+                span.set_metadata(
+                    dirty=self._ensure_pages(), tokens=int(self._upload_tokens())
+                )
                 positions = self.slots.positions_device()
             t0 = time.perf_counter()
             with TraceAnnotation("janus.decode.device"):
@@ -1300,13 +1324,12 @@ class ServingEngine:
                 next_tokens = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
             with TraceAnnotation("janus.decode.advance") as span:
                 done = len(self.completed)
-                new = self.tokens
                 for s in self.slots.active_slots:
                     req = self.slots.slot_req[s]
                     req.generated += 1
                     req.token_times.append(self.clock)
                     self.slots.advance(s)
-                    new = new.at[s, 0].set(int(next_tokens[s]))
+                    self._set_token(s, next_tokens[s])
                     if req.tokens_out is not None:
                         req.tokens_out.append(int(next_tokens[s]))
                     if req.generated >= req.output_len or self.slots.positions[s] >= self.cache_len - 2:
@@ -1315,7 +1338,6 @@ class ServingEngine:
                         req.finished = self.clock
                         self.completed.append(self.slots.release(s))
                         self._release_pages(s)
-                self.tokens = new
                 span.set_metadata(completed=len(self.completed) - done)
 
     # ------------------------------------------------------------------
@@ -1437,6 +1459,7 @@ class ServingEngine:
             }
         out["decode_stall_time"] = self.decode_stall_time
         out["prefill_chunks"] = self.prefill_worker.chunks_done
+        out["token_uploads"] = self.token_uploads
         if self.spec_k:
             out["spec"] = {
                 "k": self.spec_k,

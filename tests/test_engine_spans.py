@@ -123,6 +123,15 @@ def test_decode_carries_step_and_active_slots(mono_run):
     assert 1 in dirty
 
 
+def test_prepare_counts_token_uploads(mono_run):
+    spans, eng, _, active = mono_run
+    prepare = [s for s in spans if s[0] == "janus.decode.prepare"]
+    uploads = [s[3]["tokens"] for s in prepare]
+    assert set(uploads) <= {0, 1}
+    # every iteration feeds freshly written tokens: one upload each
+    assert sum(uploads) == eng.token_uploads == eng.metrics()["token_uploads"] == len(active)
+
+
 def test_prefill_chunks_of_a_request_cover_its_prompt(mono_run):
     spans, _, reqs, _ = mono_run
     admits = [s for s in spans if s[0] == "janus.admit"]
