@@ -2,6 +2,10 @@
 
 ``run_cell`` is what ``run.py`` calls; ``rehearse.py`` and the tests call it
 with a CPU stand-in (smaller sizes, no device look-up).
+
+A cell's deployment is its configuration's ``serving`` block with
+``deployments/<workload>.json``, where there is one, merged over it: so two
+cells of one configuration can run it on different executors or chips.
 """
 
 from __future__ import annotations
@@ -14,6 +18,7 @@ import shutil
 import sys
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -24,6 +29,7 @@ from chipbench import traffic as traffic_mod
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
 TRACE_DIR = BENCH_DIR / "traces"
+DEPLOY_DIR = BENCH_DIR / "deployments"
 
 
 def log(msg: str) -> None:
@@ -33,6 +39,16 @@ def log(msg: str) -> None:
 def load_benchmark() -> Dict:
     with open(ROOT / "BENCHMARK.json") as fh:
         return json.load(fh)
+
+
+def serving_for(workload: str, cf: Dict) -> Dict:
+    """The cell's deployment: the configuration's ``serving`` block with the
+    cell's own ``deployments/<workload>.json`` merged over it."""
+    path = DEPLOY_DIR / f"{workload}.json"
+    if not path.exists():
+        return dict(cf["serving"])
+    with open(path) as fh:
+        return dict(cf["serving"], **json.load(fh))
 
 
 def load_hardware(kind: str) -> Dict:
@@ -48,8 +64,9 @@ class Results:
     """What a metric reader sees."""
 
     workload: Dict
+    arch: ModuleType  # the configuration's layer equations and counts (``arch.py``)
     model: Dict  # the configuration file's ``program`` block
-    serving: Dict
+    serving: Dict  # the cell's deployment
     chips: int
     hw: Optional[Dict]
     setup_s: float
@@ -76,8 +93,8 @@ def run_cell(
     modes=("f32",), hooks=None,
 ) -> Dict:
     """One run.  ``stand_in`` (tests and rehearsal only) replaces the
-    configuration's ``program``/``serving`` blocks and the mix with small
-    CPU sizes and skips the device look-up.  ``modes`` adds the fp8 control's
+    configuration's ``program`` block, the deployment's sizes and the mix
+    with small CPU sizes and skips the device look-up.  ``modes`` adds the fp8 control's
     reading (``control.py``); ``hooks(engine)`` breaks the timed path
     (the fault tests).  The benchmark's own runs pass neither."""
     import jax
@@ -87,7 +104,8 @@ def run_cell(
     chips = int(wl["chips"])
     cf = harness.load_config(wl["config"])
     mix = traffic_mod.load(wl["traffic"])
-    model, serving = cf["program"], cf["serving"]
+    equations = cf["equations"]
+    model, serving = cf["program"], serving_for(workload, cf)
     if stand_in:
         model = stand_in.get("program", model)
         serving = dict(serving, **stand_in.get("serving", {}))
@@ -141,8 +159,15 @@ def run_cell(
         f"generator lateness ms p50 {np.median(late) if len(late) else 0:.3f} "
         f"p99 {np.percentile(late, 99) if len(late) else 0:.3f} max {late.max() if len(late) else 0:.3f}")
 
+    waits = sorted(
+        ((s.stamps[0] if s.stamps and s.stamps[0] <= w.t_close else w.t_close) - s.sent, len(s.item.prompt))
+        for s in w.served if w.t_open <= s.sent <= w.t_close
+    )
+    log(f"{len(waits)} requests sent in the window; the longest first-token waits (ms, prompt tokens): "
+        + ", ".join(f"{1e3 * t:.1f} ({n})" for t, n in waits[-6:]))
+
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devices)
-    res = Results(wl, model, serving, chips, hw, setup_s, w)
+    res = Results(wl, equations, model, serving, chips, hw, setup_s, w)
     out: Dict = {}
     if trace:
         from chipbench import tracing
@@ -175,7 +200,7 @@ def run_cell(
     gc.collect()
     t0 = time.perf_counter()
     verdict = check.compare(
-        model, seed, finished, chosen_f, chosen_a, kv, logits, check.load_limits(workload), modes=modes,
+        equations, model, seed, finished, chosen_f, chosen_a, kv, logits, check.load_limits(workload), modes=modes,
     )
     log(f"reference compared {len(chosen_f)} finished and {len(chosen_a)} in-flight requests "
         f"in {time.perf_counter() - t0:.1f} s; readings per mode: {verdict['readings']}; "

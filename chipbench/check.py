@@ -3,7 +3,8 @@
 Once the window has closed, two samples are drawn from the seed, each with
 its longest request: four of the requests the program finished, and
 sixteen of those still being served that the window's last decode step
-served, whose KV rows the engine still holds.  The float32 reference (``reference.py``),
+served, whose KV rows the engine still holds.  The float32 reference of the
+configuration's arch module (``arch.py``; by default ``reference.py``),
 teacher-forced on each prompt and its served tokens, then gives the numbers
 compared, each against the cell's limit in ``limits/<workload>.json``:
 
@@ -11,8 +12,9 @@ compared, each against the cell's limit in ``limits/<workload>.json``:
                          reference's best logit minus its logit of the token
                          the program served.  0 where every served token is
                          the reference's greedy choice.
-``kv_rel_err``           over the requests still being served, every layer,
-                         K and V: ||cached - reference|| / ||reference||,
+``kv_rel_err``           over the requests still being served, every layer
+                         and every cache the arch module compares (K and V
+                         by default): ||cached - reference|| / ||reference||,
                          where "cached" is what the program's prefill and
                          decode steps wrote into the paged cache.
 ``logits_rel_err``       the median over the requests still being served
@@ -28,7 +30,7 @@ compared, each against the cell's limit in ``limits/<workload>.json``:
 ``token_count_mismatches``  finished requests not served exactly the number
                          of tokens their client asked for.
 
-The control (``reference.py``'s ``fp8``) is judged as if it stood in the
+The control (the reference's ``fp8``) is judged as if it stood in the
 program's place: its own gap, K/V and logits against the same limits.
 """
 
@@ -36,11 +38,10 @@ from __future__ import annotations
 
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Sequence
 
 import numpy as np
-
-from chipbench import reference
 
 LIMIT_DIR = Path(__file__).resolve().parent / "limits"
 SAMPLE = 4  # finished requests compared: the longest and three drawn from the seed
@@ -66,20 +67,23 @@ def sample(requests: Sequence, seed: int, salt: int, k: int = SAMPLE) -> List:
 
 
 def compare(
-    model: Dict, seed: int, finished: Sequence, chosen_finished: Sequence,
+    arch: ModuleType, model: Dict, seed: int, finished: Sequence, chosen_finished: Sequence,
     chosen_active: Sequence, active_kv: Sequence, active_logits: Sequence, limits: Dict,
     modes=("f32",),
 ) -> Dict:
     """The numbers compared, each beside its limit, and a verdict for each
     mode: ``f32`` is the program, any other the control in its place.
+    ``active_kv`` holds ``harness.read_kv``'s arrays, by the engine's names;
+    the arch module's reference gets those it compares, by its own.
     ``readings`` gives every mode's value of each judged number."""
     chosen = list(chosen_finished) + list(chosen_active)
     prompts = [s.item.prompt for s in chosen]
     served = [np.asarray(s.req.tokens_out, np.int32) for s in chosen]
     pad = [None] * len(chosen_finished)
     miscount = sum(int(len(s.req.tokens_out) != s.item.out_len) for s in finished)
-    ref = reference.compare_served(
-        model, seed, prompts, served, program_kv=pad + list(active_kv),
+    own = [{name: kv[key] for key, name in arch.CACHE_KEYS.items()} for kv in active_kv]
+    ref = arch.compare_served(
+        model, seed, prompts, served, program_kv=pad + own,
         program_logits=pad + list(active_logits), modes=modes,
     )
     nan = {mode: float("nan") for mode in modes}

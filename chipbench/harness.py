@@ -14,7 +14,8 @@ change for a later PR): ``_schedule_admission``, ``_poll_prefill``,
 ``_decode_iteration``, ``slots`` (``free_slots``, ``active_slots``,
 ``num_active``, ``positions``, ``slot_req``), ``completed``, ``amax_log``,
 ``transfer_bytes_log``, ``prefill_worker.chunks_done``, ``params``,
-``caches``, ``disagg``; the window wraps ``_decode_once`` to keep the
+``caches``, ``paged``, ``disagg`` (its ``shards``, ``_pagers``, ``_kv`` and
+the module's ``_KV_KEYS``); the window wraps ``_decode_once`` to keep the
 newest step's logits for the check.
 """
 
@@ -31,30 +32,21 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from chipbench import arch
 from chipbench import traffic as traffic_mod
 
 BENCH_DIR = Path(__file__).resolve().parent
 CONFIG_DIR = BENCH_DIR / "configs"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-# ``program`` keys checked against the published names the file also holds
-_SAME = (
-    ("d_model", "hidden_size"), ("num_heads", "num_attention_heads"),
-    ("num_kv_heads", "num_key_value_heads"), ("num_layers", "num_hidden_layers"),
-    ("vocab_size", "vocab_size"), ("norm_eps", "rms_norm_eps"), ("rope_theta", "rope_theta"),
-)
-_SAME_MOE = (
-    ("num_experts", "n_routed_experts"), ("top_k", "num_experts_per_tok"),
-    ("d_ff_expert", "moe_intermediate_size"), ("num_shared_experts", "n_shared_experts"),
-)
-
-
 def load_config(name: str) -> Dict:
+    """The configuration file, with its arch module (``arch.py``) resolved
+    once under ``"equations"``."""
     with open(CONFIG_DIR / f"{name}.json") as fh:
         cf = json.load(fh)
+    cf["equations"] = arch.for_config(cf)
     prog = cf["program"]
-    pairs = _SAME + (_SAME_MOE if prog.get("num_experts") else (("d_ff", "intermediate_size"),))
-    for ours, theirs in pairs:
+    for ours, theirs in cf["equations"].published_pairs(prog):
         if float(prog[ours]) != float(cf[theirs]):
             raise ValueError(f"{name}: program {ours}={prog[ours]} but {theirs}={cf[theirs]}")
     return cf
@@ -432,23 +424,28 @@ def serve_window(
 
 
 def read_kv(engine, slot: int) -> Dict[str, np.ndarray]:
-    """The K and V rows the timed path wrote for ``slot``: ``[layers, rows,
-    kv_heads, head_dim]`` float32 on the host, rows ``0 .. positions[slot]-1``
-    (the prompt and every served token but the newest)."""
+    """Every paged cache array the timed path wrote for ``slot``, keyed by the
+    engine's name for it (``kv_k``, ``kv_v``, and any other the configuration
+    pages): ``[layers, rows, ...]`` float32 on the host, rows ``0 ..
+    positions[slot]-1`` (the prompt and every served token but the newest)."""
+    from repro.serving.disagg import _KV_KEYS
+    from repro.serving.kv_cache import PAGED_KEYS
+
     n = int(engine.slots.positions[slot])
     if engine.disagg is not None:
         ex = engine.disagg
         si = next(i for i, sh in enumerate(ex.shards) if sh.lo <= slot < sh.hi)
         pages = np.asarray(ex._pagers[si].slot_pages(slot - ex.shards[si].lo))
-        pools = {kv: [layer[kv] for layer in ex._kv[si]] for kv in ("k", "v")}
+        layers = ex._kv[si]  # the split keeps each layer's caches by short name
+        pools = {key: [layer[short] for layer in layers] for short, key in _KV_KEYS.items() if short in layers[0]}
     else:
         pages = np.asarray(engine.paged.slot_pages(slot))
-        pools = {"k": engine.caches["kv_k"], "v": engine.caches["kv_v"]}
+        pools = {key: engine.caches[key] for key in PAGED_KEYS if key in engine.caches}
     out = {}
-    for kv, pool in pools.items():
+    for key, pool in pools.items():
         layers = pool if isinstance(pool, list) else [pool[l] for l in range(pool.shape[0])]
         rows = [np.asarray(layer[pages], np.float32).reshape(-1, *layer.shape[2:])[:n] for layer in layers]
-        out[kv] = np.stack(rows)
+        out[key] = np.stack(rows)
     return out
 
 

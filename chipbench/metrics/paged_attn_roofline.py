@@ -1,7 +1,8 @@
 """Kernels: the paged decode attention kernel's share of its roofline in the
-traced window (%): the least time its calls could take -- per layer call the
+traced window (%): the least time its calls could take -- per call the
 larger of FLOPs over peak and bytes over HBM bandwidth, for the pages of the
-active slots' real lengths -- over the kernel's summed device time."""
+active slots' real lengths, as the configuration's arch module counts each
+step's calls -- over the kernel's summed device time."""
 
 from chipbench import flops
 from chipbench.metrics import spans
@@ -17,6 +18,6 @@ def read(res):
         return None
     least = 0.0
     for s in spans(res, "decode"):
-        f, b = flops.paged_attention_call(res.model, s.lengths, res.serving["kv_page_size"])
-        least += res.model["num_layers"] * flops.roofline_seconds(f, b, res.hw)
+        n, f, b = res.arch.paged_attention_calls(res.model, s.lengths, res.serving["kv_page_size"])
+        least += n * flops.roofline_seconds(f, b, res.hw)
     return 100.0 * least / t

@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
-"""Rehearse a cell on the CPU: the same loop, reference and check as
-``run.py``, at a reduced size, with no device look-up.  Prints no metrics.
+"""Rehearse a cell on the CPU: the same loop, deployment, reference and
+check as ``run.py``, at a reduced size, with no device look-up.  Prints no
+metrics.
 
     JAX_PLATFORMS=cpu python3 chipbench/rehearse.py --workload dsv2-decode
 
@@ -17,20 +18,11 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
-def reduced_program(m: dict) -> dict:
-    """The configuration's ``program`` block at CPU-test sizes (the same
-    shrink as ``ModelConfig.reduced``: 2 layers, d 256, 4 heads of 64, a
-    512-token vocabulary, 4 experts of 128, top-2, 1 shared)."""
-    r = dict(m, num_layers=2, d_model=256, num_heads=4, head_dim=64, vocab_size=512)
-    r["num_kv_heads"] = 2 if m["num_kv_heads"] < m["num_heads"] else 4
-    if m.get("num_experts"):
-        r.update(num_experts=4, top_k=2, d_ff_expert=128, num_shared_experts=1)
-    else:
-        r["d_ff"] = 512
-    return r
-
-
 def stand_in(cf: dict, mix: dict, chips: int) -> dict:
+    """CPU sizes for a cell (``cf`` as ``harness.load_config`` gives it): the
+    arch module's shrink of the ``program`` block, small deployment sizes
+    (``run_cell`` merges them over the cell's own deployment, so its
+    executor and scheduler stay) and short prompts."""
     moe = bool(cf["program"].get("num_experts"))
     serving = dict(
         max_batch=4, cache_len=256, kv_page_size=16, kv_num_pages=None, prefill_chunk=16,
@@ -45,7 +37,7 @@ def stand_in(cf: dict, mix: dict, chips: int) -> dict:
     )
     if "first_output_len" in mix:
         small["first_output_len"] = {"dist": "uniform", "low": 2, "high": 12}
-    return {"program": reduced_program(cf["program"]), "serving": serving, "mix": small}
+    return {"program": cf["equations"].reduced_program(cf["program"]), "serving": serving, "mix": small}
 
 
 def main() -> int:

@@ -45,11 +45,12 @@ def main() -> int:
     wl = next(w for w in bench["workloads"] if w["name"] == args.workload)
     cf = harness.load_config(wl["config"])
     base = traffic_mod.load(wl["traffic"])
+    serving = cell.serving_for(args.workload, cf)
     cfg = harness.model_config(cf)
     params = harness.make_params(cfg, args.seed)
     for rate in (float(r) for r in args.rates.split(",")):
         mix = dict(base, arrivals=dict(base["arrivals"], rate_per_s=rate))
-        engine = harness.build_engine(cfg, params, cf["serving"], args.seed)
+        engine = harness.build_engine(cfg, params, serving, args.seed)
         harness.warm_up(engine, mix, args.seed, cfg.vocab_size)
         gen = traffic_mod.Generator(mix, cfg.vocab_size, args.seed)
         w = harness.serve_window(engine, mix, gen, args.seconds)

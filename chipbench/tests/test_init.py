@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from chipbench import harness, reference
-from chipbench.rehearse import reduced_program
+from chipbench.archs.gqa import reduced_program
 
 SEED = 3_000_000_017  # above 2**31, as the benchmark's seeds are
 

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from chipbench import harness, reference
-from chipbench.rehearse import reduced_program
+from chipbench.archs.gqa import reduced_program
 
 SEED = 11
 
